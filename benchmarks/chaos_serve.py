@@ -73,6 +73,17 @@ def _serve(model, recovery, *, snap_box=None):
                    save_fn=lambda s, e: snap_box.setdefault(e, s))
 
 
+def resume_from(snaps: dict):
+    """Crash-restart: restore the earliest checkpoint of `snaps` into a
+    *fresh* replacer (fresh ContentionModel too — nothing carries over)
+    and finish the serve.  Returns (checkpoint epoch, report)."""
+    epoch, snap = sorted(snaps.items())[0]
+    rep = OnlineReplacer(CFG, model=ContentionModel(PCFG), policy="warm",
+                         faults=FAULTS, recovery="warm")
+    rep.restore(snap)
+    return epoch, rep.run(EVENTS, NUM_EPOCHS)
+
+
 def _report_key(rep):
     """Everything the serve produced, as a comparable value."""
     return (rep.migrations, rep.evacuations, rep.per_tenant,
@@ -111,15 +122,10 @@ def run() -> tuple[list[str], dict]:
     assert warm.migrations <= CFG.max_moves_per_epoch * NUM_EPOCHS
     assert warm.evacuations >= 1, "the core loss must force an evacuation"
 
-    # crash-restart: restore the mid-run checkpoint into a *fresh*
-    # replacer (fresh ContentionModel too — nothing carries over) and
-    # finish the serve; every report field must match bit-for-bit
+    # crash-restart from the mid-run checkpoint: every report field must
+    # match bit-for-bit
     assert snaps, "the warm serve must have checkpointed"
-    epoch, snap = sorted(snaps.items())[0]
-    rep2 = OnlineReplacer(CFG, model=ContentionModel(PCFG),
-                          policy="warm", faults=FAULTS, recovery="warm")
-    rep2.restore(snap)
-    resumed = rep2.run(EVENTS, NUM_EPOCHS)
+    epoch, resumed = resume_from(snaps)
     assert _report_key(resumed) == _report_key(warm), (
         "crash-restart diverged from the uninterrupted serve")
     rows.append(f"# crash-restart from epoch {epoch} checkpoint: "

@@ -31,19 +31,35 @@ FLEET_LATENCIES = (10, 50, 250)
 FLEET_TOTAL_STEPS = 240_000
 
 
-def run(pairs=None) -> tuple[list[str], dict]:
+def grid(pairs, **kw) -> simulator.FleetResult:
+    """The reconfigurable slot-count variants: ONE jitted sweep over the
+    whole {quanta x pairs x slot counts x latency} grid — the scheduler
+    quantum is just another sweep axis.  `kw` reaches `sweep_fleet`
+    (`path`, `use_kernel`)."""
+    return simulator.sweep_fleet(
+        scheduler.fleet_traces(pairs, TRACE_LEN), [MISS_LATENCY],
+        isa.SCENARIO_2, simulator.SchedulerConfig(),
+        slot_counts=SLOT_COUNTS, quanta=QUANTA, total_steps=TOTAL_STEPS,
+        **kw)
+
+
+def fleet_grid(fleets, quantum: int, **kw) -> simulator.FleetResult:
+    """The beyond-paper k-way fleet x miss-latency grid, one jitted call;
+    `kw` reaches `sweep_fleet`."""
+    return simulator.sweep_fleet(
+        scheduler.fleet_traces(fleets, TRACE_LEN), FLEET_LATENCIES,
+        isa.SCENARIO_2, simulator.SchedulerConfig(quantum_cycles=quantum),
+        slot_counts=(4,), total_steps=FLEET_TOTAL_STEPS, **kw)
+
+
+def run(pairs=None, res=None) -> tuple[list[str], dict]:
+    """Fig. 7 rows and per-series averages; `res` is a precomputed
+    `grid(pairs)`."""
     pairs = pairs or scheduler.make_pairs()
-    tensor = scheduler.fleet_traces(pairs, TRACE_LEN)
+    if res is None:
+        res = grid(pairs)
     rows = ["pair,series,quantum,avg_speedup_vs_IMF"]
     agg: dict = {}
-
-    # reconfigurable slot-count variants: ONE jitted sweep over the whole
-    # {quanta x pairs x slot counts x latency} grid — the scheduler quantum
-    # is just another sweep axis now
-    res = simulator.sweep_fleet(
-        tensor, [MISS_LATENCY], isa.SCENARIO_2,
-        simulator.SchedulerConfig(), slot_counts=SLOT_COUNTS,
-        quanta=QUANTA, total_steps=TOTAL_STEPS)
     cpis_all = np.asarray(res.cpi)          # (Q, B, K, 1, 2)
 
     for qi, q in enumerate(QUANTA):
@@ -98,12 +114,8 @@ def run_fleets(k: int = FLEET_K, max_fleets: int | None = 24,
     fleets = scheduler.make_fleets(k)
     if max_fleets is not None:
         fleets = fleets[:max_fleets]
-    tensor = scheduler.fleet_traces(fleets, TRACE_LEN)
     sched = simulator.SchedulerConfig(quantum_cycles=quantum)
-    res = simulator.sweep_fleet(
-        tensor, FLEET_LATENCIES, isa.SCENARIO_2, sched,
-        slot_counts=(4,), total_steps=FLEET_TOTAL_STEPS)
-    cpis = np.asarray(res.cpi)              # (B, 1, L, k)
+    cpis = np.asarray(fleet_grid(fleets, quantum).cpi)    # (B, 1, L, k)
     rows = [f"fleet,latency,avg_speedup_vs_IMF,avg_contention_vs_solo "
             f"(P={k}, 4 slots, quantum {quantum})"]
     agg: dict = {}

@@ -19,7 +19,7 @@ carry-overs).  Modules below `--min-us` are skipped (timer noise), as are
 modules present on only one side (new or retired benchmarks) and modules
 whose two sides were recorded on different backends (entries carry
 {backend, device, platform_version} provenance since PR 9 — a CPU
-baseline must never gate a GPU run).
+baseline must never gate a TPU run).
 
 Exit code 0 = within budget, 1 = regression (CI fails the step).
 """

@@ -4,7 +4,8 @@ Baseline: dense expert streaming.  Every decode step, each of the 16
 expert shards computes its 8 experts' capacity buffers through the grouped
 FFN, so each device streams all resident expert weights from HBM:
 
-    8 experts x 3 x 7168 x 4864 x 2 B  =  1.67 GB/device/step  (2.04 ms)
+    8 experts x 3 x 7168 x 4864 x 2 B  =  1.67 GB/device/step
+    (2.04 ms at v5e's 819 GB/s)
 
 Change (the paper's architecture, DESIGN.md §2): per-shard expert slots
 with the block-LRU disambiguator + slot-hit routing bias, and the
@@ -36,6 +37,21 @@ from repro.serve.engine import (EngineConfig, SlotServeEngine, Tenant,
 STEPS = 96
 SHARDS = 16
 
+# HBM bandwidth the byte model divides by, keyed by `device_kind`.
+# Source: Google Cloud documentation, "TPU v5e" (819 GB/s per chip).
+HBM_BYTES_PER_S = {"TPU v5 lite": 819e9}
+
+
+def hbm_bandwidth(device_kind: str) -> float:
+    """Bytes/s of the named device; a device not in the table is an
+    error, not a default."""
+    try:
+        return HBM_BYTES_PER_S[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HBM bandwidth recorded for device kind {device_kind!r} "
+            f"(known: {sorted(HBM_BYTES_PER_S)})") from None
+
 # instruction-mix profiles backing the 4 tenants' contention estimate:
 # mixed FM/M working sets, like the banded expert sets below
 TENANT_PROFILES = ("nbody", "minver", "matmult-int", "cubic")
@@ -58,12 +74,29 @@ def make_tenants(cfg, n=4, batch=8, width=16):
     return out
 
 
-def run() -> list[str]:
+def reduced_config():
+    """(full arctic-480b config, its width-reduced twin with the REAL
+    router dimensionality: 128 experts, top-2)."""
     cb.load_all()
     full = cb.get_config("arctic-480b")
-    # width-reduced model with the REAL router dimensionality (128 experts)
-    cfg = dataclasses.replace(
+    return full, dataclasses.replace(
         full.smoke(), num_experts=128, top_k=2, capacity_factor=8.0)
+
+
+def make_engine(cfg, params, slots: int, hit_bias: float,
+                steps: int) -> SlotServeEngine:
+    """The serving engine over the 4 banded tenants, SHARDS expert shards
+    of `slots` slots each."""
+    return SlotServeEngine(
+        cfg, params,
+        EngineConfig(quantum_tokens=16, slots_per_shard=slots,
+                     expert_shards=SHARDS, hit_bias=hit_bias),
+        make_tenants(cfg), max_len=steps + 4)
+
+
+def run() -> list[str]:
+    bw = hbm_bandwidth(jax.devices()[0].device_kind)
+    full, cfg = reduced_config()
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     mlp_mats = 3
     expert_bytes_full = mlp_mats * full.d_model * full.d_ff * 2  # 209 MB
@@ -73,16 +106,11 @@ def run() -> list[str]:
     rows = ["variant,slots,hit_bias,hit_rate,experts_live_per_step,"
             "bytes_per_step_GB,mem_term_ms,vs_base"]
     rows.append(f"base(dense-stream),-,-,-,{e_per_shard},"
-                f"{base_bytes / 1e9:.2f},{base_bytes / 819e9 * 1e3:.3f},"
+                f"{base_bytes / 1e9:.2f},{base_bytes / bw * 1e3:.3f},"
                 f"1.00x")
     for slots in (2, 4):
         for bias in (0.0, 4.0):
-            eng = SlotServeEngine(
-                cfg, params,
-                EngineConfig(quantum_tokens=16, slots_per_shard=slots,
-                             expert_shards=SHARDS, hit_bias=bias),
-                make_tenants(cfg), max_len=STEPS + 4)
-            rep = eng.run(STEPS)
+            rep = make_engine(cfg, params, slots, bias, STEPS).run(STEPS)
             # live experts per shard-step = accesses / (steps * layers...)
             layer_steps = rep["steps"] * sum(cfg.moe_layer_mask()) * SHARDS
             live = rep["accesses"] / max(layer_steps, 1)
@@ -94,7 +122,7 @@ def run() -> list[str]:
             per_step = fill_bytes + resident_bytes
             rows.append(
                 f"slots,{slots},{bias},{rep['hit_rate']:.3f},{live:.2f},"
-                f"{per_step / 1e9:.2f},{per_step / 819e9 * 1e3:.3f},"
+                f"{per_step / 1e9:.2f},{per_step / bw * 1e3:.3f},"
                 f"{base_bytes / per_step:.2f}x")
 
     # core-level contention estimate for the same 4-tenant mix, from the

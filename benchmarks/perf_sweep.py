@@ -38,17 +38,17 @@ interleaved fast path, measured head-to-head.
 6. **Fused window-distance kernel vs the jnp window pass** (PR 9): the
    `window_kernel` section, delegated to `benchmarks/window_kernel.py` —
    one-shot sweep + resumed segment through `use_kernel="kernel"`
-   (compiled Pallas on GPU/TPU, interpret mode on CPU, recorded as
+   (compiled Pallas on TPU, interpret mode on CPU, recorded as
    `kernel_mode` so the regimes are never conflated).
 
 Emits machine-readable `BENCH_sweep.json` at the repo root so the perf
 trajectory is tracked PR-over-PR, and a CSV under experiments/bench via
 benchmarks.run.  The JSON is keyed per backend (``{"cpu": {...sections,
-meta}, "gpu": {...}}``): a run replaces its own backend's section and
+meta}, "tpu": {...}}``): a run replaces its own backend's section and
 preserves the others, and every section's meta carries {backend, device,
 platform_version}.  Standalone flags::
 
-    PYTHONPATH=src python -m benchmarks.perf_sweep [--backend gpu]
+    PYTHONPATH=src python -m benchmarks.perf_sweep [--backend tpu]
     PYTHONPATH=src python -m benchmarks.perf_sweep [--interpret]
 """
 from __future__ import annotations
@@ -261,11 +261,10 @@ PG_QUANTUM = 20_000           # preempting: the paper's Fig. 7 quantum
 PG_SLOT_COUNTS = (2, 4, 8)
 PG_LATENCIES = (10, 50, 250)
 PG_PROGRAMS = (2, 3, 4)
-# always include the live default so retuning INTERLEAVE_WINDOW keeps the
-# sweep (and the interleaved_s lookup below) well-defined; 256/512/1024
-# stay fixed so the recorded sweep is comparable across backends whose
-# defaults differ (cpu retuned to 256 in PR 9, accelerators keep 512)
-PG_WINDOWS = tuple(sorted({256, 512, 1024, simulator.INTERLEAVE_WINDOW}))
+# 256/512/1024 stay fixed so the recorded sweep is comparable across
+# backends whose defaults differ (cpu retuned to 256, the TPU keeps 512);
+# the live default `simulator.interleave_window()` joins them at run time
+PG_WINDOWS = (256, 512, 1024)
 
 
 def bench_preempted_grid() -> dict:
@@ -277,6 +276,8 @@ def bench_preempted_grid() -> dict:
     scan here, recorded per fleet size in BENCH_sweep.json.
     """
     sched = simulator.SchedulerConfig(quantum_cycles=PG_QUANTUM)
+    default_w = simulator.interleave_window()
+    windows = sorted({*PG_WINDOWS, default_w})
     out = {}
     for p in PG_PROGRAMS:
         tensor = scheduler.fleet_traces(
@@ -294,8 +295,8 @@ def bench_preempted_grid() -> dict:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         scan_s = _best_of(lambda: sweep("scan"))
         window_sweep = {str(w): _best_of(lambda w=w: sweep("interleaved", w))
-                        for w in PG_WINDOWS}
-        fast_s = window_sweep[str(simulator.INTERLEAVE_WINDOW)]
+                        for w in windows}
+        fast_s = window_sweep[str(default_w)]
         out[f"p{p}"] = {
             "grid": f"{PG_FLEETS} fleets x P={p} x {PG_TOTAL_STEPS} steps, "
                     f"quantum {PG_QUANTUM}, {len(PG_SLOT_COUNTS)} slots x "
@@ -303,7 +304,7 @@ def bench_preempted_grid() -> dict:
             "scan_s": scan_s,
             "interleaved_s": fast_s,
             "speedup": scan_s / fast_s,
-            "default_window": simulator.INTERLEAVE_WINDOW,
+            "default_window": default_w,
             "window_sweep_s": window_sweep,
         }
     return out
@@ -476,7 +477,7 @@ def run() -> tuple[list[str], dict]:
 def main(print_fn=print, argv=None):
     ap = argparse.ArgumentParser(description="sweep-engine wall-clock")
     ap.add_argument("--backend", default=None,
-                    choices=("cpu", "gpu", "tpu"),
+                    choices=("cpu", "tpu"),
                     help="select the jax backend before any computation "
                          "runs (the recorded section is keyed by it)")
     ap.add_argument("--interpret", action="store_true",

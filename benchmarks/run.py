@@ -9,7 +9,7 @@ runs (``--only``) merge into the existing JSON instead of clobbering it.
     PYTHONPATH=src python -m benchmarks.run [--only fig6]
     PYTHONPATH=src python -m benchmarks.run [--only fig6,placement_search]
     PYTHONPATH=src python -m benchmarks.run --list   # names --only matches
-    PYTHONPATH=src python -m benchmarks.run --backend gpu   # JAX_PLATFORMS
+    PYTHONPATH=src python -m benchmarks.run --backend tpu   # JAX_PLATFORMS
     PYTHONPATH=src python -m benchmarks.run --interpret     # kernel parity
 
 Every recorded entry carries {backend, device, platform_version}
@@ -23,11 +23,24 @@ import json
 import os
 import time
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # anchored to the repo root (not the cwd) so partial runs always merge into
 # the same file CI uploads
-FLEET_JSON = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "BENCH_fleet.json")
+FLEET_JSON = os.path.join(REPO_ROOT, "BENCH_fleet.json")
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory:
+    wherever JAX_COMPILATION_CACHE_DIR says (JAX reads the variable
+    itself), else the fixed `<repo>/.jax_cache`, so a second run finds the
+    first run's programs.  Entry points call this; importing the library
+    never does."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _backend_meta() -> dict:
@@ -289,7 +302,7 @@ def main(argv=None) -> None:
                          "--only matches against) and exit")
     ap.add_argument("--out", default="experiments/bench")
     ap.add_argument("--backend", default=None,
-                    choices=("cpu", "gpu", "tpu"),
+                    choices=("cpu", "tpu"),
                     help="set JAX_PLATFORMS before any benchmark imports "
                          "jax (entries are stamped with the backend that "
                          "actually ran)")
@@ -316,6 +329,7 @@ def main(argv=None) -> None:
         ap.error(
             f"--only substring(s) {dead} match no registered module; "
             f"valid names: {', '.join(BENCHES)}")
+    use_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     results: dict = {}
     print("name,us_per_call,derived")

@@ -9,7 +9,7 @@ stack's epoch-advance shape) — with bit-for-bit parity asserted before
 any timing, mirroring every other engine benchmark in this directory.
 
 The kernel mode is whatever `resolve("kernel")` picks for the local
-backend: the compiled Pallas kernel on GPU/TPU, interpret mode on CPU.
+backend: the compiled Pallas kernel on TPU, interpret mode on CPU.
 Interpret mode is a correctness vehicle, not a fast path, so CPU records
 honestly show the kernel losing to XLA's fused jnp loop — the recorded
 `kernel_mode` field keeps the two regimes from ever being compared as if
@@ -95,7 +95,7 @@ def bench_kernel_vs_jnp() -> dict:
                 f"{len(WK_SLOT_COUNTS)} slots x {len(WK_LATENCIES)} "
                 f"latencies",
         "kernel_mode": mode,
-        "window": simulator.INTERLEAVE_WINDOW,
+        "window": simulator.interleave_window(),
         "jnp_s": jnp_s,
         "kernel_s": kernel_s,
         "speedup": jnp_s / kernel_s,

@@ -474,8 +474,8 @@ def op_histogram(text: str) -> dict[str, float]:
 def xla_cost_analysis(compiled) -> dict:
     """Normalised view of ``Compiled.cost_analysis()`` across jax versions.
 
-    Older jax (including the pinned 0.4.37) returns a per-device *list* of
-    property dicts; newer jax returns a single flat dict.  Callers always
+    Older jax returns a per-device *list* of property dicts; the pinned
+    jax returns a single flat dict.  Callers always
     want one flat mapping — for a per-device list we take device 0 (SPMD
     programs are identical across devices).
 

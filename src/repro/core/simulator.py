@@ -100,7 +100,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import (isa, slots, stackdist, stackdist_cold,
                         stackdist_interleaved)
 from repro.core.traces import Mix, analytic_cpi  # re-export for callers
@@ -130,17 +129,15 @@ SCAN_UNROLL = 1
 # iterations via the carried quantum-cycle counter; results are identical
 # for any window >= 1).  Backend-aware: the recorded window sweep
 # (BENCH_sweep.json, preempted_grid.*.window_sweep_s) shows 256 beating
-# 512 on every CPU preempted grid (P=2..4), so CPU defaults to 256;
-# accelerators keep 512 — wider windows amortise kernel dispatch and the
-# per-iteration gather there, and no recorded sweep argues for less.
+# 512 on every CPU preempted grid (P=2..4), so CPU defaults to 256; the
+# TPU keeps 512, untuned so far.
 _INTERLEAVE_WINDOW_BY_BACKEND = {"cpu": 256}
 
 
-def _default_interleave_window() -> int:
+def interleave_window() -> int:
+    """The default interleaved-engine window for the running backend,
+    looked up at use so importing this module initialises no backend."""
     return _INTERLEAVE_WINDOW_BY_BACKEND.get(jax.default_backend(), 512)
-
-
-INTERLEAVE_WINDOW = _default_interleave_window()
 
 
 @dataclass(frozen=True)
@@ -389,7 +386,7 @@ def _interleaved_window(quanta_grid, total_steps: int,
     windows) and never beyond the run length."""
     if window is None:
         q = int(np.max(np.asarray(quanta_grid)))
-        window = min(INTERLEAVE_WINDOW, 1 << max(0, (q - 1)).bit_length())
+        window = min(interleave_window(), 1 << max(0, (q - 1)).bit_length())
     return max(1, min(int(window), total_steps))
 
 
@@ -1388,8 +1385,8 @@ def _mesh_sweep_preempted(mesh, part, table, counts, lats, quanta_grid,
 
     out_specs = stackdist_interleaved.InterleavedGrid(
         *([spec(None, "fleet")] * 5))
-    return compat.shard_map(shard, mesh=mesh, in_specs=(spec("fleet"),),
-                            out_specs=out_specs, check_rep=False)(part)
+    return jax.shard_map(shard, mesh=mesh, in_specs=(spec("fleet"),),
+                         out_specs=out_specs, check_vma=False)(part)
 
 
 def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
@@ -1407,7 +1404,7 @@ def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
     compiled shape instead of one per batch size — compiling this sweep
     costs seconds, replaying a few padded cells costs milliseconds.  On
     multi-device hosts each chunk's fleet axis additionally shards
-    across a 1-D device mesh (`compat.shard_map`) — cells are
+    across a 1-D device mesh (`jax.shard_map`) — cells are
     independent, so sharding the batch is exact; padding rounds up to
     the device count and padded rows are sliced off as before.
     `use_kernel` picks the window-pass implementation

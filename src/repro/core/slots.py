@@ -23,8 +23,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-EMPTY = jnp.int32(-1)
+EMPTY = np.int32(-1)   # a host scalar: importing makes no device array
 
 
 class SlotState(NamedTuple):

@@ -87,9 +87,9 @@ alphabet; callers pass the per-opcode tag and cost tables.
 routes the window pass through the fused Pallas kernel
 (`repro.kernels.window_distance`) instead of the jnp body above — the
 whole per-cell loop runs on-chip with the per-tag `last_pos` vector
-resident in VMEM/registers and the (W, num_tags) occurrence matrices
+resident in registers and the (num_tags, W) occurrence matrices
 never materialised in HBM.  `None` defers to the session default
-(`window_distance.resolve`: compiled Pallas on GPU/TPU, the jnp body on
+(`window_distance.resolve`: compiled Pallas on TPU, the jnp body on
 CPU); `'kernel'`/True forces the kernel (interpret mode off-accelerator);
 `'interpret'` forces `pl.pallas_call(..., interpret=True)` — the CPU
 parity path CI proves bit-for-bit; `'jnp'`/False forces the always-

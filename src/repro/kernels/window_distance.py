@@ -12,9 +12,10 @@ of those steps is its own HBM-round-trip over the (W, num_tags) `occ` /
 This module fuses the whole pass — last-occurrence update, stack
 distance, cold/miss classification, cost cumsum and quantum-expiry
 search — into ONE Pallas kernel.  The per-tag `last_pos` vector (and in
-materialise mode `last_miss_pos`) lives in VMEM/registers as the
-`while_loop` carry for the whole cell run; the (W, num_tags) matrices
-exist only as in-kernel values and never hit HBM.  Two entry points:
+materialise mode `last_miss_pos`) lives in vector registers as the
+`while_loop` carry for the whole cell run, the per-program counters and
+cursors live in SMEM, and the (num_tags, W) matrices exist only as
+in-kernel values and never hit HBM.  Two entry points share one kernel:
 
 * `window_grid` — the one-shot counter-tuple sweep: one `pallas_call`
   whose grid is the full {quantum x fleet x slots x latency} cell grid
@@ -26,38 +27,49 @@ exist only as in-kernel values and never hit HBM.  Two entry points:
   per-tag occurrence vectors the simulator turns back into a
   `FleetState`).
 
+TPU layout: the matrices are (tags, window) — the window runs along the
+128 lanes and the tags along the 8 sublanes, so a window's per-access
+vectors are single rows and a taxonomy of ~10 tags costs two sublane
+tiles instead of a 128-lane pad.  The window read is an aligned load of
+`w_pad + 128` lanes from the VMEM-resident trace row followed by a
+dynamic lane rotation (Mosaic refuses an unaligned dynamic lane slice),
+and every scalar the loop indexes with (schedule, quanta, cursors,
+counters) lives in SMEM.
+
 All arithmetic is int32 and mirrors the jnp body operation-for-
-operation (the cumulative max/sum use a log-doubling shift scan — exact
-for integers), so interpret mode (`pl.pallas_call(..., interpret=True)`)
-is bit-for-bit equal to the jnp engine on any backend; CPU CI proves it
-without a GPU (tests/test_window_kernel.py).  Dispatch policy lives in
-`resolve()`: compiled Pallas on GPU/TPU, interpret-mode parity path on
-CPU when the kernel is forced, and the jnp body as the always-available
-fallback (the CPU default — interpret mode is a correctness vehicle, not
-a fast path).
+operation (the cumulative max/sum use a log-doubling rotate scan —
+exact for integers), so interpret mode (`pl.pallas_call(...,
+interpret=True)`) is bit-for-bit equal to the jnp engine on any backend;
+CPU CI proves it without a chip (tests/test_window_kernel.py), and
+tests/test_tpu_compile.py compiles the kernel for a described v5e.
+Dispatch policy lives in `resolve()`: compiled Pallas on TPU,
+interpret-mode parity path on CPU when the kernel is forced, and the jnp
+body as the always-available fallback (the CPU default — interpret mode
+is a correctness vehicle, not a fast path).
 
 Like its siblings in this package the kernel is shape-generic and knows
 nothing about the RISC-V alphabet; callers pass pre-gathered (P, N) tag
-and cost streams.  The tag axis is padded to the 128-lane boundary and
-the window to the 8-sublane boundary (padded tags never occur in any
-stream and padded rows carry tag -1 / cost 0, so both pads are inert —
+and cost streams.  The tag axis is padded to the 8-sublane boundary and
+the window to the 128-lane boundary (padded tags never occur in any
+stream and padded lanes carry tag -1 / cost 0, so both pads are inert —
 see the parity argument in tests/test_window_kernel.py).
 """
 from __future__ import annotations
 
 import functools
 import os
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["window_grid", "window_cell", "resolve", "set_default_mode",
            "DEFAULT_MODE"]
 
-_LANES = 128      # TPU lane width: tag-axis pad boundary
-_SUBLANES = 8     # TPU sublane width: window-axis pad boundary
+_LANES = 128      # TPU lane width: window-axis pad boundary
+_SUBLANES = 8     # TPU sublane width: tag-axis pad boundary
+_INT_MIN = int(jnp.iinfo(jnp.int32).min)
 
 # knob vocabulary for the `use_kernel` dispatch (see `resolve`); the
 # session-wide default can be preset via the REPRO_WINDOW_KERNEL env var
@@ -80,10 +92,10 @@ def resolve(use_kernel=None) -> tuple[bool, bool]:
     """Resolve a `use_kernel` knob value to (run_kernel, interpret).
 
     None -> the session default mode (env REPRO_WINDOW_KERNEL or 'auto');
-    True/'kernel' -> the kernel, compiled on GPU/TPU and interpret-mode
+    True/'kernel' -> the kernel, compiled on TPU and interpret-mode
     elsewhere; 'interpret' -> the kernel in interpret mode everywhere
     (the CPU parity path); False/'jnp' -> the jnp window pass.  'auto'
-    picks the compiled kernel on GPU/TPU and the jnp body on CPU, where
+    picks the compiled kernel on TPU and the jnp body on CPU, where
     interpret mode would be strictly slower than XLA's fused loop.
     """
     mode = use_kernel
@@ -96,11 +108,11 @@ def resolve(use_kernel=None) -> tuple[bool, bool]:
     if mode not in _MODES:
         raise ValueError(f"unknown use_kernel value {use_kernel!r} "
                          f"(expected None/bool or one of {_MODES})")
-    accel = jax.default_backend() in ("gpu", "tpu")
+    tpu = jax.default_backend() == "tpu"
     if mode == "auto":
-        return accel, False
+        return tpu, False
     if mode == "kernel":
-        return True, not accel
+        return True, not tpu
     if mode == "interpret":
         return True, True
     return False, False
@@ -109,186 +121,229 @@ def resolve(use_kernel=None) -> tuple[bool, bool]:
 def _interp(interpret) -> bool:
     if interpret is not None:
         return bool(interpret)
-    return jax.default_backend() not in ("gpu", "tpu")
+    return jax.default_backend() != "tpu"
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
-def _iota(n: int) -> jnp.ndarray:
-    # 1-D iota via a 2-D broadcasted_iota (plain 1-D iota fails on TPU)
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
-
-
-def _shift_scan(x: jnp.ndarray, op, unit) -> jnp.ndarray:
-    """Inclusive scan along axis 0 by log-doubling shifts — exact for the
-    integer max/add monoids, and built from static slices/concats only so
-    it lowers inside a kernel body (no `lax.associative_scan`)."""
-    n = x.shape[0]
+def _lane_scan(x: jnp.ndarray, op, unit) -> jnp.ndarray:
+    """Inclusive scan along lanes (axis 1) by log-doubling rotations —
+    exact for the integer max/add monoids, and built from static lane
+    rotations only so it lowers inside a kernel body."""
+    n = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     shift = 1
     while shift < n:
-        pad = jnp.full((shift,) + x.shape[1:], unit, x.dtype)
-        x = op(x, jnp.concatenate([pad, x[:-shift]], axis=0))
+        x = op(x, jnp.where(lane >= shift, pltpu.roll(x, shift, 1), unit))
         shift *= 2
     return x
 
 
-def _cummax0(x: jnp.ndarray) -> jnp.ndarray:
-    return _shift_scan(x, jnp.maximum, jnp.iinfo(jnp.int32).min)
+def _pads(window: int, num_tags: int, trace_len: int):
+    """(w_pad, t_pad, ext): padded window lanes, padded tag sublanes, and
+    the wrapped trace length that keeps every aligned window read of
+    `w_pad + 128` lanes starting below `trace_len` in bounds."""
+    w_pad = _round_up(max(int(window), 1), _LANES)
+    t_pad = _round_up(max(int(num_tags), 1), _SUBLANES)
+    ext = _round_up(trace_len, _LANES) + w_pad
+    return w_pad, t_pad, ext
 
 
-def _cumsum0(x: jnp.ndarray) -> jnp.ndarray:
-    return _shift_scan(x, jnp.add, 0)
+# SMEM counter block: five (P,) fields, in this order
+_CURSORS, _CYCLES, _INSTRS, _MISSES, _BS_MISSES = range(5)
 
 
-class _Carry(NamedTuple):
-    """In-kernel cell state: `CellCarry` with the per-tag vectors held as
-    (1, t_pad) VMEM-resident rows (always including `last_miss`, so one
-    loop body serves both modes; non-materialise runs simply never update
-    it)."""
+def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
+            tags_ref, costs_ref, seed_last_ref, out_ref, out_last_ref,
+            cnt_ref, *, num_progs, trace_len, total_steps, window, w_pad,
+            pos_base, materialise):
+    """One cell per grid step: `_simulate_cell`'s while-loop with every
+    window intermediate kept on-chip.  `tags_ref`/`costs_ref` hold the
+    fleet's (P, ext) wrapped streams; `seed_ref` is the flat SMEM seed
+    (five (P,) counter fields, then sched_idx, q_cycles, switches)."""
+    q, k, l = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    num_active = counts_ref[k]
+    miss_latency = lats_ref[l]
+    handler, bs_extra = misc_ref[0], misc_ref[1]
+    sched_len = sched_ref.shape[0]
+    span = w_pad + _LANES
+    for i in range(5 * num_progs):
+        cnt_ref[i] = seed_ref[i]
 
-    last_pos: jnp.ndarray   # (1, t_pad)
-    last_miss: jnp.ndarray  # (1, t_pad)
-    cursors: jnp.ndarray    # (P,)
-    sched_idx: jnp.ndarray  # ()
-    steps_done: jnp.ndarray  # ()
-    q_cycles: jnp.ndarray   # ()
-    cycles: jnp.ndarray     # (P,)
-    instrs: jnp.ndarray     # (P,)
-    misses: jnp.ndarray     # (P,)
-    bs_misses: jnp.ndarray  # (P,)
-    switches: jnp.ndarray   # ()
+    t_pad = seed_last_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, w_pad), 1)
+    valid = lane < window
+    tag_ids = jax.lax.broadcasted_iota(jnp.int32, (t_pad, w_pad), 0)
 
+    def read_window(ref, p, start):
+        # aligned superset read, then rotate lane `start` down to lane 0
+        base = pl.multiple_of((start // _LANES) * _LANES, _LANES)
+        row = ref[0, pl.ds(p, 1), pl.ds(base, span)]
+        row = pltpu.roll(row, (span - (start - base)) % span, 1)
+        return row[:, :w_pad]
 
-def _window_loop(tags, costs, num_active, miss_latency, quanta_vec,
-                 sched, handler, bs_extra, init: _Carry, *, trace_len: int,
-                 total_steps: int, window: int, w_pad: int, t_pad: int,
-                 pos_base: int, materialise: bool) -> _Carry:
-    """The fused cell run: `_simulate_cell`'s while-loop, every window
-    intermediate kept on-chip.  `tags`/`costs` are (P, reps*trace_len)
-    VMEM values pre-tiled so one dynamic slice at `cursor % trace_len`
-    reads a wrapped window (a window longer than the trace wraps through
-    the extra replicas)."""
-    num_progs = tags.shape[0]
-    sched_len = sched.shape[0]
-    warange = _iota(w_pad)
-    valid = warange < window
-    tag_ids = jax.lax.broadcasted_iota(jnp.int32, (w_pad, t_pad), 1)
-    parange = _iota(num_progs)
-
-    def body(c: _Carry) -> _Carry:
-        p = sched[c.sched_idx]
-        start = jnp.remainder(c.cursors[p], trace_len)
-        w_tags = jax.lax.dynamic_slice(tags, (p, start), (1, w_pad))[0]
-        w_hw = jax.lax.dynamic_slice(costs, (p, start), (1, w_pad))[0]
-        # padded rows are inert: tag -1 never slots, cost 0 keeps the
+    def body(c):
+        last_pos, last_miss, sched_idx, steps_done, q_cycles, switches = c
+        p = sched_ref[sched_idx]
+        cursor = cnt_ref[_CURSORS * num_progs + p]
+        start = jax.lax.rem(cursor, jnp.int32(trace_len))
+        # padded lanes are inert: tag -1 never slots, cost 0 keeps the
         # cost cumsum flat past the real window
-        w_tags = jnp.where(valid, w_tags, jnp.int32(-1))
-        w_hw = jnp.where(valid, w_hw, jnp.int32(0))
+        w_tags = jnp.where(valid, read_window(tags_ref, p, start), -1)
+        w_hw = jnp.where(valid, read_window(costs_ref, p, start), 0)
         slotted = w_tags >= 0
 
-        pos = jnp.int32(pos_base) + c.steps_done + warange
-        match = w_tags[:, None] == tag_ids
-        occ = jnp.where(match, pos[:, None], jnp.int32(-1))
-        cm = _cummax0(occ)
-        # state observed by each access: the previous row's cummax (row 0
-        # sees nothing in-window) floored with the carried last_pos
+        pos = pos_base + steps_done + lane
+        match = w_tags == tag_ids
+        occ = jnp.where(match, pos, -1)
+        cm = _lane_scan(occ, jnp.maximum, _INT_MIN)
+        # state observed by each access: the previous lane's cummax (lane
+        # 0 sees nothing in-window) floored with the carried last_pos
         prev = jnp.maximum(
-            jnp.concatenate([jnp.full((1, t_pad), -1, jnp.int32),
-                             cm[:-1]], axis=0),
-            c.last_pos)
-        sel = jnp.clip(w_tags, 0)[:, None] == tag_ids
-        prev_self = jnp.sum(jnp.where(sel, prev, 0), axis=1)
+            jnp.where(lane >= 1, pltpu.roll(cm, 1, 1), -1), last_pos)
+        sel = jnp.maximum(w_tags, 0) == tag_ids
+        prev_self = jnp.sum(jnp.where(sel, prev, 0), axis=0, keepdims=True)
         cold = slotted & (prev_self < 0)
-        dist = jnp.sum((prev > prev_self[:, None]).astype(jnp.int32),
-                       axis=1)
+        dist = jnp.sum((prev > prev_self).astype(jnp.int32), axis=0,
+                       keepdims=True)
         miss = slotted & (cold | (dist >= num_active))
 
         cost = (w_hw + jnp.where(miss, miss_latency, 0)
-                + jnp.where(cold, bs_extra, 0)).astype(jnp.int32)
-        cum = c.q_cycles + _cumsum0(cost)
-        expire = cum >= quanta_vec[p]
-        any_exp = jnp.any(expire)
-        # padded rows repeat cum[window-1], so the first expiring index is
-        # always a real row when any real row expires
-        first = jnp.min(jnp.where(expire, warange, jnp.int32(w_pad)))
+                + jnp.where(cold, bs_extra, 0))
+        cum = q_cycles + _lane_scan(cost, jnp.add, 0)
+        expire = cum >= quanta_ref[q * num_progs + p]
+        # padded lanes repeat cum[window-1], so the first expiring lane is
+        # always a real one when any real lane expires
+        first = jnp.min(jnp.where(expire, lane, w_pad))
+        any_exp = first < w_pad
         n_exp = jnp.where(any_exp, first + 1, jnp.int32(window))
-        remaining = (jnp.int32(total_steps) - c.steps_done)
+        remaining = total_steps - steps_done
         n = jnp.minimum(n_exp, remaining)
         do_switch = any_exp & (n_exp <= remaining)
 
-        last_row = warange == (n - 1)
-        committed = jnp.max(
-            jnp.where(last_row[:, None], cm, jnp.int32(-1)), axis=0)
-        end_cum = jnp.sum(jnp.where(last_row, cum, 0))
+        last_lane = lane == n - 1
+        committed = jnp.max(jnp.where(last_lane, cm, -1), axis=1,
+                            keepdims=True)
+        end_cum = jnp.sum(jnp.where(last_lane, cum, 0))
         if materialise:
-            cm_miss = _cummax0(jnp.where(match & miss[:, None],
-                                         pos[:, None], jnp.int32(-1)))
-            committed_miss = jnp.max(
-                jnp.where(last_row[:, None], cm_miss, jnp.int32(-1)),
-                axis=0)
-            last_miss = jnp.maximum(c.last_miss, committed_miss[None, :])
-        else:
-            last_miss = c.last_miss
-        run_cycles = (end_cum - c.q_cycles
-                      + jnp.where(do_switch, handler, 0).astype(jnp.int32))
-        in_run = warange < n
-        onehot = (parange == p).astype(jnp.int32)
-        return _Carry(
-            last_pos=jnp.maximum(c.last_pos, committed[None, :]),
-            last_miss=last_miss,
-            cursors=c.cursors + onehot * n,
-            sched_idx=jnp.where(do_switch,
-                                (c.sched_idx + 1) % sched_len,
-                                c.sched_idx),
-            steps_done=c.steps_done + n,
-            q_cycles=jnp.where(do_switch, 0, end_cum).astype(jnp.int32),
-            cycles=c.cycles + onehot * run_cycles,
-            instrs=c.instrs + onehot * n,
-            misses=c.misses + onehot * jnp.sum(
-                (miss & in_run).astype(jnp.int32)),
-            bs_misses=c.bs_misses + onehot * jnp.sum(
-                (cold & in_run).astype(jnp.int32)),
-            switches=c.switches + do_switch.astype(jnp.int32),
-        )
+            cm_miss = _lane_scan(jnp.where(match & miss, pos, -1),
+                                 jnp.maximum, _INT_MIN)
+            last_miss = jnp.maximum(last_miss, jnp.max(
+                jnp.where(last_lane, cm_miss, -1), axis=1, keepdims=True))
+        run_cycles = end_cum - q_cycles + jnp.where(do_switch, handler, 0)
+        in_run = lane < n
 
-    return jax.lax.while_loop(
-        lambda c: c.steps_done < total_steps, body, init)
+        def add(field, delta):
+            idx = field * num_progs + p
+            cnt_ref[idx] = cnt_ref[idx] + delta
+
+        add(_CURSORS, n)
+        add(_CYCLES, run_cycles)
+        add(_INSTRS, n)
+        add(_MISSES, jnp.sum((miss & in_run).astype(jnp.int32)))
+        add(_BS_MISSES, jnp.sum((cold & in_run).astype(jnp.int32)))
+        return (jnp.maximum(last_pos, committed), last_miss,
+                jnp.where(do_switch, (sched_idx + 1) % sched_len,
+                          sched_idx),
+                steps_done + n,
+                jnp.where(do_switch, 0, end_cum),
+                switches + do_switch.astype(jnp.int32))
+
+    seed_sca = 5 * num_progs
+    init = (seed_last_ref[...],
+            jnp.full((t_pad, 1), -1, jnp.int32),
+            seed_ref[seed_sca], jnp.int32(0), seed_ref[seed_sca + 1],
+            seed_ref[seed_sca + 2])
+    last_pos, last_miss, sched_idx, steps_done, q_cycles, switches = \
+        jax.lax.while_loop(lambda c: c[3] < total_steps, body, init)
+
+    # counters out as one (8, 128) tile: rows 0-4 the (P,) fields in lanes
+    # 0..P-1, row 5 lanes 0-3 (sched_idx, steps_done, q_cycles, switches)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 1)
+    tile = jnp.zeros((8, _LANES), jnp.int32)
+    for f in range(5):
+        for i in range(num_progs):
+            tile = jnp.where((rows == f) & (cols == i),
+                             cnt_ref[f * num_progs + i], tile)
+    for j, v in enumerate((sched_idx, steps_done, q_cycles, switches)):
+        tile = jnp.where((rows == 5) & (cols == j), v, tile)
+    out_ref[0] = tile
+    lanes_t = jax.lax.broadcasted_iota(jnp.int32, (t_pad, _LANES), 1)
+    out_last_ref[0] = jnp.where(lanes_t == 0, last_pos,
+                                jnp.where(lanes_t == 1, last_miss, -1))
 
 
-def _pads(window: int, num_tags: int, trace_len: int):
-    w_pad = _round_up(max(int(window), 1), _SUBLANES)
-    t_pad = max(_round_up(max(int(num_tags), 1), _LANES), _LANES)
-    # one extra trace replica per w_pad/trace_len so a window slice
-    # starting anywhere in [0, trace_len) stays in bounds
-    reps = 1 + -(-w_pad // int(trace_len))
-    return w_pad, t_pad, reps
+def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
+         handler, bs_miss_extra, seed=None, *, num_tags: int,
+         total_steps: int, window: int, pos_base: int, materialise: bool,
+         interpret):
+    """The shared pallas_call: (B, P, N) streams over the (Q, B, K, L)
+    cell grid, every cell starting from the same `seed` — None (cold) or
+    (flat SMEM seed, (num_tags,) last_pos).  Returns the (Q, B, K, L, 8,
+    128) counter tiles and the (Q, B, K, L, t_pad, 128) last_pos (lane 0)
+    / last_miss (lane 1) tiles."""
+    ptags = jnp.asarray(ptags, jnp.int32)
+    num_fleets, num_progs, trace_len = ptags.shape
+    if num_progs > _LANES:
+        raise ValueError(f"window kernel supports at most {_LANES} "
+                         f"programs per fleet, got {num_progs}")
+    slot_counts = jnp.asarray(slot_counts, jnp.int32).reshape(-1)
+    miss_latencies = jnp.asarray(miss_latencies, jnp.int32).reshape(-1)
+    quanta = jnp.asarray(quanta, jnp.int32).reshape(-1, num_progs)
+    nq, nk, nl = quanta.shape[0], slot_counts.shape[0], \
+        miss_latencies.shape[0]
+    w_pad, t_pad, ext = _pads(window, num_tags, trace_len)
+    # wrap each stream past its end so an aligned read of w_pad + 128
+    # lanes from any start in [0, trace_len) stays in bounds
+    reps = -(-ext // trace_len)
+    pcosts = jnp.asarray(pcosts, jnp.int32)
+    tags_t = jnp.tile(ptags, (1, 1, reps))[..., :ext]
+    costs_t = jnp.tile(pcosts, (1, 1, reps))[..., :ext]
+    misc = jnp.stack([jnp.asarray(handler, jnp.int32),
+                      jnp.asarray(bs_miss_extra, jnp.int32)])
+    seed_last = jnp.full((t_pad, 1), -1, jnp.int32)
+    if seed is None:
+        seed_flat = jnp.zeros((5 * num_progs + 3,), jnp.int32)
+    else:
+        seed_flat, s_last = seed
+        seed_last = seed_last.at[:num_tags, 0].set(s_last)
+    kernel = functools.partial(
+        _kernel, num_progs=num_progs, trace_len=trace_len,
+        total_steps=int(total_steps), window=int(window), w_pad=w_pad,
+        pos_base=pos_base, materialise=materialise)
+    ncells = nq * num_fleets * nk * nl
 
+    def cell(q, b, k, l):
+        return (((q * num_fleets + b) * nk + k) * nl + l, 0, 0)
 
-def _grid_kernel(tags_ref, costs_ref, counts_ref, lats_ref, quanta_ref,
-                 sched_ref, misc_ref, cyc_ref, ins_ref, mis_ref, bsm_ref,
-                 sw_ref, *, t_pad, trace_len, total_steps, window, w_pad):
-    tags = tags_ref[0]
-    costs = costs_ref[0]
-    num_progs = tags.shape[0]
-    zeros_p = jnp.zeros((num_progs,), jnp.int32)
-    init = _Carry(
-        last_pos=jnp.full((1, t_pad), -1, jnp.int32),
-        last_miss=jnp.full((1, t_pad), -1, jnp.int32),
-        cursors=zeros_p, sched_idx=jnp.int32(0), steps_done=jnp.int32(0),
-        q_cycles=jnp.int32(0), cycles=zeros_p, instrs=zeros_p,
-        misses=zeros_p, bs_misses=zeros_p, switches=jnp.int32(0))
-    final = _window_loop(
-        tags, costs, counts_ref[0], lats_ref[0], quanta_ref[0],
-        sched_ref[...], misc_ref[0], misc_ref[1], init,
-        trace_len=trace_len, total_steps=total_steps, window=window,
-        w_pad=w_pad, t_pad=t_pad, pos_base=0, materialise=False)
-    cyc_ref[0, 0, 0, 0, :] = final.cycles
-    ins_ref[0, 0, 0, 0, :] = final.instrs
-    mis_ref[0, 0, 0, 0, :] = final.misses
-    bsm_ref[0, 0, 0, 0, :] = final.bs_misses
-    sw_ref[0, 0, 0, 0] = final.switches
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    stream = pl.BlockSpec((1, num_progs, ext), lambda q, b, k, l: (b, 0, 0))
+    # both streams, double-buffered, with the sublane axis padded to 8
+    stream_bytes = 2 * 2 * _round_up(num_progs, _SUBLANES) * ext * 4
+    tiles, last = pl.pallas_call(
+        kernel,
+        grid=(nq, num_fleets, nk, nl),
+        in_specs=[smem] * 6 + [stream, stream,
+                               pl.BlockSpec((t_pad, 1),
+                                            lambda q, b, k, l: (0, 0))],
+        out_specs=[pl.BlockSpec((1, 8, _LANES), cell),
+                   pl.BlockSpec((1, t_pad, _LANES), cell)],
+        out_shape=[jax.ShapeDtypeStruct((ncells, 8, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((ncells, t_pad, _LANES),
+                                        jnp.int32)],
+        scratch_shapes=[pltpu.SMEM((5 * num_progs,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=stream_bytes + (16 << 20)),
+        interpret=_interp(interpret),
+    )(slot_counts, miss_latencies, quanta.reshape(-1),
+      jnp.asarray(schedule, jnp.int32).reshape(-1), misc, seed_flat,
+      tags_t, costs_t, seed_last)
+    shape = (nq, num_fleets, nk, nl)
+    return (tiles.reshape(shape + (8, _LANES)),
+            last.reshape(shape + (t_pad, _LANES)))
 
 
 @functools.partial(jax.jit, static_argnames=("num_tags", "total_steps",
@@ -300,87 +355,14 @@ def window_grid(ptags, pcosts, slot_counts, miss_latencies, quanta,
     the 5 `InterleavedGrid` arrays, one fused-kernel cell per point of
     the (Q, B, K, L) Pallas grid.  Bit-for-bit equal to
     `stackdist_interleaved.sweep_preempted`'s jnp path."""
-    ptags = jnp.asarray(ptags, jnp.int32)
-    pcosts = jnp.asarray(pcosts, jnp.int32)
-    slot_counts = jnp.asarray(slot_counts, jnp.int32).reshape(-1)
-    miss_latencies = jnp.asarray(miss_latencies, jnp.int32).reshape(-1)
-    quanta = jnp.asarray(quanta, jnp.int32)
-    schedule = jnp.asarray(schedule, jnp.int32).reshape(-1)
-    num_fleets, num_progs, trace_len = ptags.shape
-    nq, nk, nl = quanta.shape[0], slot_counts.shape[0], \
-        miss_latencies.shape[0]
-    sched_len = schedule.shape[0]
-    w_pad, t_pad, reps = _pads(window, num_tags, trace_len)
-    tags_t = jnp.tile(ptags, (1, 1, reps))
-    costs_t = jnp.tile(pcosts, (1, 1, reps))
-    misc = jnp.stack([jnp.asarray(handler, jnp.int32),
-                      jnp.asarray(bs_miss_extra, jnp.int32)])
-    tiled = trace_len * reps
-    kernel = functools.partial(
-        _grid_kernel, t_pad=t_pad, trace_len=trace_len,
-        total_steps=int(total_steps), window=int(window), w_pad=w_pad)
-    grid = (nq, num_fleets, nk, nl)
-    pvec = jax.ShapeDtypeStruct((nq, num_fleets, nk, nl, num_progs),
-                                jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, num_progs, tiled), lambda q, b, k, l: (b, 0, 0)),
-            pl.BlockSpec((1, num_progs, tiled), lambda q, b, k, l: (b, 0, 0)),
-            pl.BlockSpec((1,), lambda q, b, k, l: (k,)),
-            pl.BlockSpec((1,), lambda q, b, k, l: (l,)),
-            pl.BlockSpec((1, num_progs), lambda q, b, k, l: (q, 0)),
-            pl.BlockSpec((sched_len,), lambda q, b, k, l: (0,)),
-            pl.BlockSpec((2,), lambda q, b, k, l: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, 1, num_progs),
-                         lambda q, b, k, l: (q, b, k, l, 0)),
-            pl.BlockSpec((1, 1, 1, 1, num_progs),
-                         lambda q, b, k, l: (q, b, k, l, 0)),
-            pl.BlockSpec((1, 1, 1, 1, num_progs),
-                         lambda q, b, k, l: (q, b, k, l, 0)),
-            pl.BlockSpec((1, 1, 1, 1, num_progs),
-                         lambda q, b, k, l: (q, b, k, l, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda q, b, k, l: (q, b, k, l)),
-        ],
-        out_shape=[pvec, pvec, pvec, pvec,
-                   jax.ShapeDtypeStruct((nq, num_fleets, nk, nl),
-                                        jnp.int32)],
-        interpret=_interp(interpret),
-    )(tags_t, costs_t, slot_counts, miss_latencies, quanta, schedule, misc)
-
-
-def _cell_kernel(tags_ref, costs_ref, args_ref, quanta_ref, sched_ref,
-                 seed_vec_ref, seed_sca_ref, seed_last_ref, out_last_ref,
-                 out_miss_ref, out_vec_ref, out_sca_ref, *, t_pad,
-                 trace_len, total_steps, window, w_pad, pos_base,
-                 materialise):
-    tags = tags_ref[...]
-    costs = costs_ref[...]
-    seed_vec = seed_vec_ref[...]
-    seed_sca = seed_sca_ref[...]
-    init = _Carry(
-        last_pos=seed_last_ref[...],
-        last_miss=jnp.full((1, t_pad), -1, jnp.int32),
-        cursors=seed_vec[0], sched_idx=seed_sca[0],
-        steps_done=jnp.int32(0), q_cycles=seed_sca[1],
-        cycles=seed_vec[1], instrs=seed_vec[2], misses=seed_vec[3],
-        bs_misses=seed_vec[4], switches=seed_sca[2])
-    final = _window_loop(
-        tags, costs, args_ref[0], args_ref[1], quanta_ref[...],
-        sched_ref[...], args_ref[2], args_ref[3], init,
-        trace_len=trace_len, total_steps=total_steps, window=window,
-        w_pad=w_pad, t_pad=t_pad, pos_base=pos_base,
-        materialise=materialise)
-    out_last_ref[...] = final.last_pos
-    out_miss_ref[...] = final.last_miss
-    out_vec_ref[...] = jnp.stack([final.cursors, final.cycles,
-                                  final.instrs, final.misses,
-                                  final.bs_misses])
-    out_sca_ref[...] = jnp.stack([final.sched_idx, final.steps_done,
-                                  final.q_cycles, final.switches])
+    num_progs = ptags.shape[1]
+    tiles, _ = _run(ptags, pcosts, slot_counts, miss_latencies, quanta,
+                    schedule, handler, bs_miss_extra, num_tags=num_tags,
+                    total_steps=total_steps, window=window, pos_base=0,
+                    materialise=False, interpret=interpret)
+    return (tiles[..., _CYCLES, :num_progs], tiles[..., _INSTRS, :num_progs],
+            tiles[..., _MISSES, :num_progs],
+            tiles[..., _BS_MISSES, :num_progs], tiles[..., 5, 3])
 
 
 @functools.partial(jax.jit, static_argnames=("num_tags", "total_steps",
@@ -399,57 +381,22 @@ def window_cell(ptags, pcosts, num_active, miss_latency, quanta, schedule,
     tail of the returned fields)."""
     if seeded is None:
         seeded = seed is not None
-    ptags = jnp.asarray(ptags, jnp.int32)
-    pcosts = jnp.asarray(pcosts, jnp.int32)
-    quanta = jnp.asarray(quanta, jnp.int32).reshape(-1)
-    schedule = jnp.asarray(schedule, jnp.int32).reshape(-1)
-    num_progs, trace_len = ptags.shape
-    sched_len = schedule.shape[0]
-    w_pad, t_pad, reps = _pads(window, num_tags, trace_len)
-    tags_t = jnp.tile(ptags, (1, reps))
-    costs_t = jnp.tile(pcosts, (1, reps))
-    args = jnp.stack([jnp.asarray(num_active, jnp.int32),
-                      jnp.asarray(miss_latency, jnp.int32),
-                      jnp.asarray(handler, jnp.int32),
-                      jnp.asarray(bs_miss_extra, jnp.int32)])
-    zeros_p = jnp.zeros((num_progs,), jnp.int32)
-    if seed is None:
-        seed_last = jnp.full((num_tags,), -1, jnp.int32)
-        seed_vec = jnp.stack([zeros_p] * 5)
-        seed_sca = jnp.zeros((3,), jnp.int32)
-    else:
+    num_progs = ptags.shape[0]
+    if seed is not None:
         (s_last, s_cursors, s_sched, s_qc, s_cycles, s_instrs, s_misses,
          s_bsm, s_switches) = seed
-        seed_last = jnp.asarray(s_last, jnp.int32)
-        seed_vec = jnp.stack([jnp.asarray(x, jnp.int32) for x in
-                              (s_cursors, s_cycles, s_instrs, s_misses,
-                               s_bsm)])
-        seed_sca = jnp.stack([jnp.asarray(s_sched, jnp.int32),
-                              jnp.asarray(s_qc, jnp.int32),
-                              jnp.asarray(s_switches, jnp.int32)])
-    seed_last = jnp.full((1, t_pad), -1, jnp.int32).at[0, :num_tags].set(
-        seed_last)
-    tiled = trace_len * reps
-    kernel = functools.partial(
-        _cell_kernel, t_pad=t_pad, trace_len=trace_len,
-        total_steps=int(total_steps), window=int(window), w_pad=w_pad,
-        pos_base=num_tags if seeded else 0, materialise=bool(materialise))
-    full = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-    out_last, out_miss, out_vec, out_sca = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[full((num_progs, tiled)), full((num_progs, tiled)),
-                  full((4,)), full((num_progs,)), full((sched_len,)),
-                  full((5, num_progs)), full((3,)), full((1, t_pad))],
-        out_specs=[full((1, t_pad)), full((1, t_pad)),
-                   full((5, num_progs)), full((4,))],
-        out_shape=[jax.ShapeDtypeStruct((1, t_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, t_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((5, num_progs), jnp.int32),
-                   jax.ShapeDtypeStruct((4,), jnp.int32)],
-        interpret=_interp(interpret),
-    )(tags_t, costs_t, args, quanta, schedule, seed_vec, seed_sca,
-      seed_last)
-    return (out_last[0, :num_tags], out_miss[0, :num_tags], out_vec[0],
-            out_sca[0], out_sca[1], out_sca[2], out_vec[1], out_vec[2],
-            out_vec[3], out_vec[4], out_sca[3])
+        seed = (jnp.concatenate(
+            [jnp.asarray(x, jnp.int32).reshape(-1) for x in
+             (s_cursors, s_cycles, s_instrs, s_misses, s_bsm, s_sched,
+              s_qc, s_switches)]), jnp.asarray(s_last, jnp.int32))
+    tiles, last = _run(
+        jnp.asarray(ptags)[None], jnp.asarray(pcosts)[None], num_active,
+        miss_latency, quanta, schedule, handler, bs_miss_extra, seed,
+        num_tags=num_tags, total_steps=total_steps, window=window,
+        pos_base=num_tags if seeded else 0, materialise=bool(materialise),
+        interpret=interpret)
+    tile, last = tiles[0, 0, 0, 0], last[0, 0, 0, 0]
+    vec = tile[:5, :num_progs]
+    return (last[:num_tags, 0], last[:num_tags, 1], vec[_CURSORS],
+            tile[5, 0], tile[5, 1], tile[5, 2], vec[_CYCLES], vec[_INSTRS],
+            vec[_MISSES], vec[_BS_MISSES], tile[5, 3])

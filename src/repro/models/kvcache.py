@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -125,7 +124,7 @@ def decode_attention_sharded(q, cache, k_new, v_new, pos, cfg, mesh,
         o = o_glob / jnp.maximum(l_glob[..., None], 1e-30)
         return o.reshape(b, 1, h, dh).astype(qs.dtype)
 
-    o = shard_map(
+    o = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, P(b_spec)),
         out_specs=q_spec,

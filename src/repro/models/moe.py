@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -172,7 +171,7 @@ def moe_apply_sharded(p, x, cfg, mesh, data_axes=("data",),
         load = jax.lax.psum(load, data_axes)  # global per-layer expert load
         return y.reshape(b, t, d), load
 
-    y, load = shard_map(
+    y, load = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), w_spec, w_spec, w_spec, x_spec),
         out_specs=(x_spec, P()),

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,6 +70,6 @@ def cross_pod_mean_tree(grads, ef_state, mesh, pod_axis: str = "pod"):
     specs = jax.tree_util.tree_map(lambda l: P(*([pod_axis] + [None] * (
         l.ndim - 1))) if l.ndim else P(pod_axis), grads)
     # leaves carry a leading per-pod dim in the demo layout
-    return shard_map(body, mesh=mesh, in_specs=(specs, specs),
-                     out_specs=(specs, specs), check_vma=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=(specs, specs),
+                         out_specs=(specs, specs), check_vma=False)(
         grads, ef_state)

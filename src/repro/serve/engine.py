@@ -75,7 +75,8 @@ class SlotServeEngine:
                              for _ in range(engine_cfg.expert_shards)]
         self.deferred: list[Tenant] = []   # tenants parked by admission
         self.stats = {"fills": 0, "accesses": 0, "fill_seconds": 0.0,
-                      "steps": 0, "per_tenant": {t.name: 0 for t in tenants}}
+                      "steps": 0, "nonfinite_steps": 0,
+                      "per_tenant": {t.name: 0 for t in tenants}}
         for t in tenants:
             t.cache = transformer.init_cache(cfg, t.tokens.shape[0], max_len)
         self._decode = jax.jit(
@@ -137,6 +138,8 @@ class SlotServeEngine:
         if rb is not None:
             batch["router_bias"] = rb
         logits, cache, aux = self._decode(self.params, tenant.cache, batch)
+        if not bool(jnp.isfinite(logits).all()):
+            self.stats["nonfinite_steps"] += 1
         tenant.cache = cache
         tenant.position += 1
         tenant.done_tokens += b

@@ -29,7 +29,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 # At 256+ chips FSDP (params sharded over data) is strictly better for
 # every assigned arch: the per-layer all-gather overlaps with compute and
@@ -209,7 +208,7 @@ class ShardingPlan:
         ]
 
         def spec_for(path, leaf):
-            name = compat.keystr(path, simple=True, separator="/")
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
             # strip list indices like segments/0/1/... and factored-moment
             # suffixes (opt v = {r, c}) so they inherit the parent's rule
             clean = re.sub(r"/\d+", "", name)
@@ -264,7 +263,7 @@ class ShardingPlan:
         dp, m = self.dp, self.model_axis
 
         def spec_for(path, leaf):
-            name = compat.keystr(path, simple=True, separator="/")
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
             shape = leaf.shape
             if re.search(r"/(k|v)$", name):
                 if shape[2] > max(self.cfg.window, 1):  # full cache

@@ -36,6 +36,7 @@ staying compilable on the CPU backend in ~1s per phase.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -207,7 +208,10 @@ def _abstract_batch(cfg, phase: str) -> dict:
 
 
 def _compiled_phase(arch: str, phase: str):
-    """Lower + compile one (smoke config, phase) cell; returns Compiled."""
+    """Lower + compile one (smoke config, phase) cell for the CPU device,
+    whatever the default backend; returns Compiled.  One fixed target
+    keeps the mix, and every trace lowered from it, the same bits on a
+    chip host as in the CPU tests."""
     import jax
 
     from repro.configs import base as cb
@@ -217,15 +221,17 @@ def _compiled_phase(arch: str, phase: str):
     cfg = cb.get_config(arch).smoke()
     params = jax.eval_shape(lambda k: transformer.init_params(cfg, k),
                             jax.random.PRNGKey(0))
+    cpu = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
+    on_cpu = functools.partial(jax.jit, in_shardings=cpu, out_shardings=cpu)
     pre = _abstract_batch(cfg, "prefill")
     if phase == "prefill":
         fn = lambda p, bt: transformer.prefill(cfg, p, bt)[0]  # noqa: E731
-        return jax.jit(fn).lower(params, pre).compile()
+        return on_cpu(fn).lower(params, pre).compile()
     _, cache, _ = jax.eval_shape(
         lambda p, bt: transformer.prefill(cfg, p, bt), params, pre)
     dec = _abstract_batch(cfg, "decode")
     fn = lambda p, c, bt: transformer.decode_step(cfg, p, bt, c)[0]  # noqa: E731
-    return jax.jit(fn).lower(params, cache, dec).compile()
+    return on_cpu(fn).lower(params, cache, dec).compile()
 
 
 def model_opcount(arch: str, phase: str) -> OpCount:

@@ -164,7 +164,7 @@ def test_restore_rejects_mismatched_topology_geometry(model):
 # ---------------------------------------------------------------------------
 
 PROV = {"backend": "cpu", "device": "TFRT_CPU_0",
-        "platform_version": "jax-0.4.37"}
+        "platform_version": "jax-0.9.0"}
 
 
 def _entry(us, **extra):

@@ -626,11 +626,11 @@ def test_perf_gate_compare():
                        max_slowdown=1.25, min_us=100_000, modules=["other"])
     assert fails and "vacuous" in fails[0]
     # same-backend rule: entries recorded on different backends are never
-    # compared (a CPU baseline must not gate a GPU run); provenance-free
+    # compared (a CPU baseline must not gate a TPU run); provenance-free
     # pre-PR-9 entries keep the old behaviour
     rows, fails = compare(
         {"fig6": {"us_per_call": 1_000_000, "backend": "cpu"}},
-        {"fig6": {"us_per_call": 9_000_000, "backend": "gpu"}},
+        {"fig6": {"us_per_call": 9_000_000, "backend": "tpu"}},
         max_slowdown=1.25, min_us=100_000)
     assert not fails
     assert any("backend" in r for r in rows)

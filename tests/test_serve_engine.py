@@ -48,6 +48,13 @@ def test_round_robin_shares_steps(moe_setup):
     assert abs(per["t0"] - per["t1"]) <= 8
 
 
+def test_nonfinite_logits_are_counted(moe_setup):
+    cfg, params = moe_setup
+    assert run_engine(cfg, params, steps=4)["nonfinite_steps"] == 0
+    nan_params = jax.tree_util.tree_map(lambda x: x * np.nan, params)
+    assert run_engine(cfg, nan_params, steps=4)["nonfinite_steps"] == 4
+
+
 def test_more_slots_fewer_fills(moe_setup):
     cfg, params = moe_setup
     r2 = run_engine(cfg, params, slots_per_shard=2)

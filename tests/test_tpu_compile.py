@@ -1,0 +1,145 @@
+"""Compile the main path's device programs for a described TPU v5e.
+
+Interpret mode (tests/test_window_kernel.py) proves what the window
+kernel computes; only the TPU compiler says whether it lowers at all —
+block shapes, dynamic indexing, scalar placement, VMEM.  These tests
+compile, without a chip, at the shapes the benchmarks and
+`chip_smoke.py` run:
+
+* `window_grid` at the Fig. 7 grid and the P=4 fleet sweep;
+* `window_cell` at the chaos-serve resume shape (N=4 000) and the
+  fleet-scale shape (N=768, ~8 tenants per core);
+* the jnp `_sweep_impl` at the Fig. 7 grid, which must fit one chip;
+* the fleet-axis mesh sweep (`simulator._mesh_sweep_preempted`) over
+  four chips, running the kernel on each.
+
+The kernel entry points are compiled directly with `interpret=False`:
+`sweep_fleet` asks the running backend, which is the CPU here.  The
+topology is described inside a fixture, never at import, so every
+pytest-xdist worker collects the same tests.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
+
+from repro.core import isa, simulator
+from repro.core import stackdist_interleaved as sdi
+from repro.kernels import window_distance as wd
+
+HBM_BYTES = 16 * 2**30   # one v5e chip
+WINDOW = 512             # simulator.interleave_window() on a TPU
+NUM_TAGS = int(np.max(simulator.fleet_tag_table(isa.SCENARIO_2, 2))) + 1
+
+# (fleets padded to the batch bucket, programs, trace length, steps,
+#  quantum cells, slot counts, latencies) of each sweep grid
+FIG7 = (52, 2, 60_000, 160_000, 2, 3, 1)
+P4_FLEETS = (24, 4, 60_000, 240_000, 1, 1, 3)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _i32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _fits_one_chip(compiled):
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, mem
+    return used
+
+
+def _grid_args(shape, sharding):
+    b, p, n, _, q, k, l = shape
+    return (_i32((b, p, n), sharding), _i32((b, p, n), sharding),
+            _i32((k,), sharding), _i32((l,), sharding),
+            _i32((q, p), sharding), _i32((p,), sharding),
+            _i32((), sharding), _i32((), sharding))
+
+
+@pytest.mark.parametrize("shape", [FIG7, P4_FLEETS], ids=["fig7", "p4"])
+def test_window_grid_compiles(one_chip, shape):
+    compiled = jax.jit(
+        lambda *a: wd.window_grid(*a, num_tags=NUM_TAGS,
+                                  total_steps=shape[3], window=WINDOW,
+                                  interpret=False)
+    ).lower(*_grid_args(shape, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+# (programs, trace length, steps): a chaos-serve epoch advance and probe
+# (PlacementConfig trace_len 4 000, epoch 8 000 / probe 2 000 steps), and
+# a fleet-scale core of 8 tenants (trace_len 768, epoch 1 024 steps)
+@pytest.mark.parametrize("p,n,steps", [(4, 4_000, 8_000), (2, 4_000, 2_000),
+                                       (8, 768, 1_024)],
+                         ids=["chaos-epoch", "chaos-probe", "fleet-scale"])
+def test_window_cell_compiles(one_chip, p, n, steps):
+    s = lambda *shape: _i32(shape, one_chip)  # noqa: E731
+    seed = (s(NUM_TAGS), s(p), s(), s(), s(p), s(p), s(p), s(p), s())
+    compiled = jax.jit(
+        lambda *a: wd.window_cell(*a, num_tags=NUM_TAGS, total_steps=steps,
+                                  window=WINDOW, materialise=True,
+                                  interpret=False)
+    ).lower(s(p, n), s(p, n), s(), s(), s(p), s(p), s(), s(),
+            seed).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+def test_jnp_sweep_fits_one_chip(one_chip):
+    """The jnp window pass (`use_kernel="jnp"`, and the vmap^4 the kernel
+    replaces) at the Fig. 7 grid compiles and fits one chip's HBM."""
+    b, p, n, steps, q, k, l = FIG7
+    s = lambda *shape: _i32(shape, one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda *a: sdi._sweep_impl(*a, num_tags=NUM_TAGS,
+                                   total_steps=steps, window=WINDOW,
+                                   kernel=False, interpret=False)
+    ).lower(s(b, p, n), s(p, isa.NUM_INSTRUCTIONS), s(isa.NUM_INSTRUCTIONS),
+            s(k), s(l), s(q, p), s(p), s(), s()).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    _fits_one_chip(compiled)
+
+
+def test_fleet_mesh_sweep_compiles_for_four_chips(topo, monkeypatch):
+    """The fleet axis sharded over a 2x2 mesh, each chip running the
+    compiled kernel over its 13 of the Fig. 7 grid's 52 fleets."""
+    # steer the dispatcher to the compiled kernel: the backend here is CPU
+    monkeypatch.setattr(sdi.window_distance, "resolve",
+                        lambda use_kernel=None: (True, False))
+    mesh = Mesh(np.array(topo.devices), ("fleet",))
+    b, p, n, steps, _, _, _ = FIG7
+    table = simulator.fleet_tag_table(isa.SCENARIO_2, p)
+    part = jax.ShapeDtypeStruct((b, p, n), jnp.int32,
+                                sharding=NamedSharding(
+                                    mesh, PartitionSpec("fleet")))
+    compiled = jax.jit(
+        lambda pt: simulator._mesh_sweep_preempted(
+            mesh, pt, table, jnp.asarray([2, 4, 8], jnp.int32),
+            jnp.asarray([50], jnp.int32),
+            np.asarray([[1_000] * p, [20_000] * p]), np.arange(p), 150, 100,
+            NUM_TAGS, steps, WINDOW, None)
+    ).lower(part).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _fits_one_chip(compiled) > 0

@@ -6,16 +6,16 @@ is only ever allowed to return results bit-for-bit identical to the
 reference (`stackdist_interleaved._simulate_cell`), so the whole suite
 runs the kernel in interpret mode (`pl.pallas_call(..., interpret=True)`)
 and asserts exact integer equality — CPU CI proves the kernel without a
-GPU.  Two inertness claims carry the proof from the padded kernel shapes
+chip.  Two inertness claims carry the proof from the padded kernel shapes
 back to the unpadded jnp pass, and the randomized sweeps below exercise
 both:
 
-* tag pad (-> 128 lanes): padded tag columns never occur in any stream,
+* tag pad (-> 8 sublanes): padded tag rows never occur in any stream,
   so their `prev` entries stay -1 and are never > `prev_self`, never
   counted in a distance, and commit -1 back into `last_pos`;
-* window pad (-> 8 sublanes): padded rows carry tag -1 / cost 0, so the
-  cost cumsum is flat past the real window and a padded row expires iff
-  row `window-1` already did — the first expiring index is always real.
+* window pad (-> 128 lanes): padded lanes carry tag -1 / cost 0, so the
+  cost cumsum is flat past the real window and a padded lane expires iff
+  lane `window-1` already did — the first expiring index is always real.
 
 Layout mirrors the PR-5 scan-parity harness (test_stackdist_interleaved):
 white-box kernel-vs-jnp checks, dispatcher `use_kernel` semantics, a
@@ -47,7 +47,7 @@ CFG = simulator.ReconfigConfig(num_slots=4, miss_latency=50)
 # ---------------------------------------------------------------------------
 
 def test_resolve_knob_mapping():
-    accel = jax.default_backend() in ("gpu", "tpu")
+    accel = jax.default_backend() == "tpu"
     assert wd.resolve("auto") == (accel, False)
     assert wd.resolve("kernel") == (True, not accel)
     assert wd.resolve(True) == (True, not accel)
