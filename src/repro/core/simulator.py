@@ -1244,6 +1244,36 @@ def simulate_many(traces: np.ndarray, cfg: ReconfigConfig,
     return (res, _canonical_state(final)) if return_state else res
 
 
+# Host spans of the sweep entries' stages, on the profiler's clock (a
+# constructor call each when no trace is taken): `sim.plan` (input checks, tag table,
+# eligibility, path choice), `sim.stage` (inputs put in an engine's
+# form), `sim.launch` (the engine's host dispatch) and `sim.assemble`
+# (the result put together), inside one `sim.sweep_fleet` or
+# `sim.sweep_bitstream` span per call.  Every span opens and closes on
+# the host, outside jit, and waits for nothing on the device.
+_span = jax.profiler.TraceAnnotation
+
+# grid cells the sweep engines were asked for, and the cells they were
+# launched over (padded fleet rows included); see `cell_counts`
+_cells_real = 0
+_cells_launched = 0
+
+
+def _count_cells(real: int, launched: int) -> None:
+    global _cells_real, _cells_launched
+    _cells_real += int(real)
+    _cells_launched += int(launched)
+
+
+def cell_counts() -> dict:
+    """Grid cells the sweep engines were asked for (`cells_real`) and
+    launched over (`cells_launched`: fleet rows the interleaved engine
+    pads to its batch bucket or the mesh count their cells too), summed
+    over the process's `sweep_fleet` and `sweep_bitstream` calls and
+    `simulate_many`'s one-shot interleaved runs."""
+    return {"cells_real": _cells_real, "cells_launched": _cells_launched}
+
+
 @functools.partial(
     jax.jit, static_argnames=("num_slots", "bs_entries", "total_steps",
                               "scan_unroll"))
@@ -1419,35 +1449,43 @@ def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
     ndev = mesh.devices.size if mesh is not None else 1
     grids = []
     for i in range(0, b_total, chunk):
-        part = jnp.asarray(fleets[i:i + chunk])
-        if b_total > chunk:
-            target = chunk          # tail rides the full-chunk shape
-        else:
-            target = min(-(-b_total // _INTERLEAVED_BATCH_BUCKET)
-                         * _INTERLEAVED_BATCH_BUCKET, chunk)
-        target = -(-target // ndev) * ndev   # mesh: divisible fleet shards
-        pad = target - part.shape[0]
-        if pad > 0:
-            part = jnp.concatenate(
-                [part, jnp.broadcast_to(part[:1],
-                                        (pad,) + part.shape[1:])], axis=0)
-        if mesh is not None:
-            grids.append(_mesh_sweep_preempted(
-                mesh, part, table, counts, lats, quanta_grid, schedule,
-                handler, bs_miss_extra, num_tags, total_steps, w,
-                use_kernel))
-        else:
-            grids.append(stackdist_interleaved.sweep_preempted(
-                part, table, isa.INSTR_HW_CYCLES, counts, lats,
-                jnp.asarray(quanta_grid, jnp.int32),
-                jnp.asarray(schedule, jnp.int32), jnp.int32(handler),
-                jnp.int32(bs_miss_extra), num_tags=num_tags,
-                total_steps=total_steps, window=w, use_kernel=use_kernel))
-    return FleetResult(*(jnp.concatenate([g[f] for g in grids],
-                                         axis=1)[:, :b_total]
-                         for f in range(5)))
+        with _span("sim.stage"):
+            part = jnp.asarray(fleets[i:i + chunk])
+            if b_total > chunk:
+                target = chunk          # tail rides the full-chunk shape
+            else:
+                target = min(-(-b_total // _INTERLEAVED_BATCH_BUCKET)
+                             * _INTERLEAVED_BATCH_BUCKET, chunk)
+            target = -(-target // ndev) * ndev  # mesh: divisible shards
+            real = part.shape[0]
+            pad = target - real
+            if pad > 0:
+                part = jnp.concatenate(
+                    [part, jnp.broadcast_to(part[:1],
+                                            (pad,) + part.shape[1:])],
+                    axis=0)
+        _count_cells(cells * real, cells * target)
+        with _span("sim.launch"):
+            if mesh is not None:
+                grids.append(_mesh_sweep_preempted(
+                    mesh, part, table, counts, lats, quanta_grid, schedule,
+                    handler, bs_miss_extra, num_tags, total_steps, w,
+                    use_kernel))
+            else:
+                grids.append(stackdist_interleaved.sweep_preempted(
+                    part, table, isa.INSTR_HW_CYCLES, counts, lats,
+                    jnp.asarray(quanta_grid, jnp.int32),
+                    jnp.asarray(schedule, jnp.int32), jnp.int32(handler),
+                    jnp.int32(bs_miss_extra), num_tags=num_tags,
+                    total_steps=total_steps, window=w,
+                    use_kernel=use_kernel))
+    with _span("sim.assemble"):
+        return FleetResult(*(jnp.concatenate([g[f] for g in grids],
+                                             axis=1)[:, :b_total]
+                             for f in range(5)))
 
 
+@functools.partial(jax.profiler.annotate_function, name="sim.sweep_fleet")
 def sweep_fleet(fleets: np.ndarray, miss_latencies, scenarios,
                 sched: SchedulerConfig, *, slot_counts, quanta=None,
                 bs_cache_entries: int = 64, bs_miss_extra: int = 100,
@@ -1485,79 +1523,94 @@ def sweep_fleet(fleets: np.ndarray, miss_latencies, scenarios,
     ineligible); all engines return bit-for-bit identical results on
     eligible grids.
     """
-    fleets = jnp.asarray(fleets, jnp.int32)
-    if fleets.ndim != 3:
-        raise ValueError(
-            f"sweep_fleet expects (B, P, N) fleet traces, got shape "
-            f"{tuple(fleets.shape)}")
-    num_progs = fleets.shape[1]
-    table = fleet_tag_table(scenarios, num_progs)
-    counts = jnp.asarray(slot_counts, jnp.int32).reshape(-1)
-    lats = jnp.asarray(miss_latencies, jnp.int32).reshape(-1)
-    if quanta is None:
-        quanta_grid = sched.quanta(num_progs)[None, :]          # (1, P)
-    else:
-        if np.isscalar(quanta) or getattr(quanta, "ndim", None) == 0:
+    with _span("sim.plan"):
+        fleets = jnp.asarray(fleets, jnp.int32)
+        if fleets.ndim != 3:
             raise ValueError(
-                f"quanta must be a sequence of quantum cells (scalars or "
-                f"per-program vectors), got bare scalar {quanta!r} — pass "
-                f"quanta=[{quanta!r}] for a single-cell axis")
-        quanta = list(quanta)
-        if not quanta:
-            raise ValueError("quanta needs at least one quantum cell")
-        quanta_grid = np.stack([quanta_vector(q, num_progs) for q in quanta])
-    eligible = stackdist_eligible(
-        table[0], quantum_cycles=quanta_grid,
-        bs_entries=bs_cache_entries,
-        max_miss_latency=int(np.max(np.asarray(miss_latencies))),
-        bs_miss_extra=bs_miss_extra, total_steps=total_steps)
-    inter_eligible = interleaved_eligible(
-        table, bs_entries=bs_cache_entries, miss_latencies=lats,
-        bs_miss_extra=bs_miss_extra, handler_cycles=sched.handler_cycles,
-        total_steps=total_steps)
-    inter_auto = _interleaved_auto_ok(
-        quanta_grid, quanta_grid.shape[0] * counts.shape[0] * lats.shape[0],
-        int(np.max(table)) + 1, total_steps, interleave_window)
-    cold_eligible = stackdist_cold_eligible(
-        quantum_cycles=quanta_grid,
-        max_miss_latency=int(np.max(np.asarray(miss_latencies))),
-        bs_miss_extra=bs_miss_extra, total_steps=total_steps)
-    chosen = _check_path(path, eligible, inter_eligible, inter_auto,
-                         cold_eligible)
-    if chosen in ("stackdist", "stackdist_cold"):
-        if chosen == "stackdist":
-            res = _sweep_fleet_stackdist(fleets, table, lats, counts,
-                                         bs_miss_extra, total_steps)
+                f"sweep_fleet expects (B, P, N) fleet traces, got shape "
+                f"{tuple(fleets.shape)}")
+        num_progs = fleets.shape[1]
+        table = fleet_tag_table(scenarios, num_progs)
+        counts = jnp.asarray(slot_counts, jnp.int32).reshape(-1)
+        lats = jnp.asarray(miss_latencies, jnp.int32).reshape(-1)
+        if quanta is None:
+            quanta_grid = sched.quanta(num_progs)[None, :]      # (1, P)
         else:
-            res = _sweep_fleet_stackdist_cold(
-                fleets, table, lats, counts, bs_cache_entries,
-                bs_miss_extra, total_steps)
+            if np.isscalar(quanta) or getattr(quanta, "ndim", None) == 0:
+                raise ValueError(
+                    f"quanta must be a sequence of quantum cells (scalars "
+                    f"or per-program vectors), got bare scalar "
+                    f"{quanta!r} — pass quanta=[{quanta!r}] for a "
+                    f"single-cell axis")
+            quanta = list(quanta)
+            if not quanta:
+                raise ValueError("quanta needs at least one quantum cell")
+            quanta_grid = np.stack([quanta_vector(q, num_progs)
+                                    for q in quanta])
+        eligible = stackdist_eligible(
+            table[0], quantum_cycles=quanta_grid,
+            bs_entries=bs_cache_entries,
+            max_miss_latency=int(np.max(np.asarray(miss_latencies))),
+            bs_miss_extra=bs_miss_extra, total_steps=total_steps)
+        inter_eligible = interleaved_eligible(
+            table, bs_entries=bs_cache_entries, miss_latencies=lats,
+            bs_miss_extra=bs_miss_extra,
+            handler_cycles=sched.handler_cycles, total_steps=total_steps)
+        grid_cells = quanta_grid.shape[0] * counts.shape[0] * lats.shape[0]
+        inter_auto = _interleaved_auto_ok(
+            quanta_grid, grid_cells, int(np.max(table)) + 1, total_steps,
+            interleave_window)
+        cold_eligible = stackdist_cold_eligible(
+            quantum_cycles=quanta_grid,
+            max_miss_latency=int(np.max(np.asarray(miss_latencies))),
+            bs_miss_extra=bs_miss_extra, total_steps=total_steps)
+        chosen = _check_path(path, eligible, inter_eligible, inter_auto,
+                             cold_eligible)
+        schedule = sched.schedule(num_progs)
+    if chosen != "interleaved":     # which counts its padded chunks itself
+        _count_cells(grid_cells * fleets.shape[0],
+                     grid_cells * fleets.shape[0])
+    if chosen == "interleaved":
+        res = _sweep_fleet_interleaved(
+            fleets, table, lats, counts, quanta_grid, schedule,
+            sched.handler_cycles, bs_miss_extra, total_steps,
+            interleave_window, use_kernel)
+    elif chosen == "scan":
+        with _span("sim.stage"):
+            s_max = int(np.max(np.asarray(slot_counts)))
+            quanta_d, schedule_d = (jnp.asarray(quanta_grid),
+                                    jnp.asarray(schedule))
+            handler = jnp.int32(sched.handler_cycles)
+            extra = jnp.int32(bs_miss_extra)
+        with _span("sim.launch"):
+            res = _sweep_fleet(fleets, table, lats, counts, quanta_d,
+                               schedule_d, handler, s_max, bs_cache_entries,
+                               extra, total_steps, scan_unroll)
+    else:
+        with _span("sim.launch"):
+            if chosen == "stackdist":
+                res = _sweep_fleet_stackdist(fleets, table, lats, counts,
+                                             bs_miss_extra, total_steps)
+            else:
+                res = _sweep_fleet_stackdist_cold(
+                    fleets, table, lats, counts, bs_cache_entries,
+                    bs_miss_extra, total_steps)
         if quanta is None:
             return res
         # every quantum cell is unpreempted, so cells are identical:
         # broadcast the one reconstructed grid over the quantum axis
-        q = quanta_grid.shape[0]
-        return FleetResult(*(jnp.broadcast_to(x[None], (q,) + x.shape)
-                             for x in res))
-    if chosen == "interleaved":
-        res = _sweep_fleet_interleaved(
-            fleets, table, lats, counts, quanta_grid,
-            sched.schedule(num_progs), sched.handler_cycles, bs_miss_extra,
-            total_steps, interleave_window, use_kernel)
-        if quanta is None:
-            return FleetResult(*(x[0] for x in res))
-        return res
-    s_max = int(np.max(np.asarray(slot_counts)))
-    res = _sweep_fleet(
-        fleets, table, lats, counts, jnp.asarray(quanta_grid),
-        jnp.asarray(sched.schedule(num_progs)),
-        jnp.int32(sched.handler_cycles), s_max, bs_cache_entries,
-        jnp.int32(bs_miss_extra), total_steps, scan_unroll)
+        with _span("sim.assemble"):
+            q = quanta_grid.shape[0]
+            return FleetResult(*(jnp.broadcast_to(x[None], (q,) + x.shape)
+                                 for x in res))
     if quanta is None:
-        return FleetResult(*(x[0] for x in res))
+        with _span("sim.assemble"):
+            return FleetResult(*(x[0] for x in res))
     return res
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="sim.sweep_bitstream")
 def sweep_bitstream(traces: np.ndarray, scenario: isa.SlotScenario, *,
                     slot_counts, miss_latencies, bs_entries, bs_miss_extras,
                     total_steps: int,
@@ -1576,61 +1629,68 @@ def sweep_bitstream(traces: np.ndarray, scenario: isa.SlotScenario, *,
     whole capacity x penalty sub-grid; `path="scan"` forces one
     cycle-by-cycle run per grid cell (the parity reference).
     """
-    traces = jnp.asarray(traces, jnp.int32)
-    if traces.ndim != 2:
-        raise ValueError(
-            f"sweep_bitstream expects (B, N) solo traces, got shape "
-            f"{tuple(traces.shape)}")
-    counts = np.asarray(slot_counts, np.int32).reshape(-1)
-    lats = np.asarray(miss_latencies, np.int32).reshape(-1)
-    caps = np.asarray(bs_entries, np.int32).reshape(-1)
-    extras = np.asarray(bs_miss_extras, np.int32).reshape(-1)
-    cold_ok = stackdist_cold_eligible(
-        quantum_cycles=NO_PREEMPT_QUANTUM,
-        max_miss_latency=int(np.max(lats)),
-        bs_miss_extra=int(np.max(extras)), total_steps=total_steps)
-    if path not in ("auto", "stackdist_cold", "scan"):
-        raise ValueError(
-            f"unknown path {path!r} — sweep_bitstream accepts "
-            f"'auto'|'stackdist_cold'|'scan'")
-    if path == "stackdist_cold" and not cold_ok:
-        raise ValueError(
-            "stacked cold-bitstream path requires an unpreempted run with "
-            "int32-safe costs (see simulator.stackdist_cold_eligible)")
+    with _span("sim.plan"):
+        traces = jnp.asarray(traces, jnp.int32)
+        if traces.ndim != 2:
+            raise ValueError(
+                f"sweep_bitstream expects (B, N) solo traces, got shape "
+                f"{tuple(traces.shape)}")
+        counts = np.asarray(slot_counts, np.int32).reshape(-1)
+        lats = np.asarray(miss_latencies, np.int32).reshape(-1)
+        caps = np.asarray(bs_entries, np.int32).reshape(-1)
+        extras = np.asarray(bs_miss_extras, np.int32).reshape(-1)
+        cold_ok = stackdist_cold_eligible(
+            quantum_cycles=NO_PREEMPT_QUANTUM,
+            max_miss_latency=int(np.max(lats)),
+            bs_miss_extra=int(np.max(extras)), total_steps=total_steps)
+        if path not in ("auto", "stackdist_cold", "scan"):
+            raise ValueError(
+                f"unknown path {path!r} — sweep_bitstream accepts "
+                f"'auto'|'stackdist_cold'|'scan'")
+        if path == "stackdist_cold" and not cold_ok:
+            raise ValueError(
+                "stacked cold-bitstream path requires an unpreempted run "
+                "with int32-safe costs (see "
+                "simulator.stackdist_cold_eligible)")
+    b = traces.shape[0]
+    shape = (b, counts.size, lats.size, caps.size, extras.size)
+    _count_cells(np.prod(shape), np.prod(shape))
     if path != "scan" and cold_ok:
-        return stackdist_cold.sweep_cold(
-            traces, scenario.instr_tag, isa.INSTR_HW_CYCLES,
-            jnp.asarray(counts), jnp.asarray(lats), jnp.asarray(caps),
-            jnp.asarray(extras), num_tags=max(scenario.num_tags, 1),
-            total_steps=total_steps)
+        with _span("sim.stage"):
+            args = (jnp.asarray(counts), jnp.asarray(lats),
+                    jnp.asarray(caps), jnp.asarray(extras))
+        with _span("sim.launch"):
+            return stackdist_cold.sweep_cold(
+                traces, scenario.instr_tag, isa.INSTR_HW_CYCLES, *args,
+                num_tags=max(scenario.num_tags, 1), total_steps=total_steps)
     # reference fallback: one scan per cell (slot/bitstream misses do not
     # depend on the latency/penalty axes in an unpreempted run, so the
     # counter fields come from the first L x X cell)
-    b = traces.shape[0]
-    shape = (b, counts.size, lats.size, caps.size, extras.size)
     cycles = np.zeros(shape, np.int32)
     slot_misses = np.zeros(shape[:2], np.int32)
     bs_misses = np.zeros((b, counts.size, caps.size), np.int32)
-    for i in range(b):
-        stream = traces[i][jnp.remainder(
-            jnp.arange(total_steps, dtype=jnp.int32), traces.shape[-1])]
-        for k, s in enumerate(counts):
-            for e, cap in enumerate(caps):
-                for l, lat in enumerate(lats):
-                    for x, pen in enumerate(extras):
-                        r = simulate_single(
-                            stream,
-                            ReconfigConfig(num_slots=int(s),
-                                           miss_latency=int(lat),
-                                           bs_cache_entries=int(cap),
-                                           bs_miss_extra=int(pen)),
-                            scenario, path="scan")
-                        cycles[i, k, l, e, x] = int(r.cycles)
-                        slot_misses[i, k] = int(r.slot_misses)
-                        bs_misses[i, k, e] = int(r.bs_misses)
-    return stackdist_cold.ColdGrid(cycles=jnp.asarray(cycles),
-                                   slot_misses=jnp.asarray(slot_misses),
-                                   bs_misses=jnp.asarray(bs_misses))
+    with _span("sim.launch"):
+        for i in range(b):
+            stream = traces[i][jnp.remainder(
+                jnp.arange(total_steps, dtype=jnp.int32), traces.shape[-1])]
+            for k, s in enumerate(counts):
+                for e, cap in enumerate(caps):
+                    for l, lat in enumerate(lats):
+                        for x, pen in enumerate(extras):
+                            r = simulate_single(
+                                stream,
+                                ReconfigConfig(num_slots=int(s),
+                                               miss_latency=int(lat),
+                                               bs_cache_entries=int(cap),
+                                               bs_miss_extra=int(pen)),
+                                scenario, path="scan")
+                            cycles[i, k, l, e, x] = int(r.cycles)
+                            slot_misses[i, k] = int(r.slot_misses)
+                            bs_misses[i, k, e] = int(r.bs_misses)
+    with _span("sim.assemble"):
+        return stackdist_cold.ColdGrid(cycles=jnp.asarray(cycles),
+                                       slot_misses=jnp.asarray(slot_misses),
+                                       bs_misses=jnp.asarray(bs_misses))
 
 
 # --- pair path: the P=2 special case, kept as thin wrappers so the Fig. 7

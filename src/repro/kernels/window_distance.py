@@ -279,12 +279,13 @@ def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
 def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
          handler, bs_miss_extra, seed=None, *, num_tags: int,
          total_steps: int, window: int, pos_base: int, materialise: bool,
-         interpret):
+         interpret, name: str):
     """The shared pallas_call: (B, P, N) streams over the (Q, B, K, L)
     cell grid, every cell starting from the same `seed` — None (cold) or
     (flat SMEM seed, (num_tags,) last_pos).  Returns the (Q, B, K, L, 8,
     128) counter tiles and the (Q, B, K, L, t_pad, 128) last_pos (lane 0)
-    / last_miss (lane 1) tiles."""
+    / last_miss (lane 1) tiles.  `name` is the kernel's HLO instruction
+    name, which device traces show whatever jit encloses the call."""
     ptags = jnp.asarray(ptags, jnp.int32)
     num_fleets, num_progs, trace_len = ptags.shape
     if num_progs > _LANES:
@@ -338,6 +339,7 @@ def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=stream_bytes + (16 << 20)),
         interpret=_interp(interpret),
+        name=name,
     )(slot_counts, miss_latencies, quanta.reshape(-1),
       jnp.asarray(schedule, jnp.int32).reshape(-1), misc, seed_flat,
       tags_t, costs_t, seed_last)
@@ -359,7 +361,8 @@ def window_grid(ptags, pcosts, slot_counts, miss_latencies, quanta,
     tiles, _ = _run(ptags, pcosts, slot_counts, miss_latencies, quanta,
                     schedule, handler, bs_miss_extra, num_tags=num_tags,
                     total_steps=total_steps, window=window, pos_base=0,
-                    materialise=False, interpret=interpret)
+                    materialise=False, interpret=interpret,
+                    name="window_grid")
     return (tiles[..., _CYCLES, :num_progs], tiles[..., _INSTRS, :num_progs],
             tiles[..., _MISSES, :num_progs],
             tiles[..., _BS_MISSES, :num_progs], tiles[..., 5, 3])
@@ -394,7 +397,7 @@ def window_cell(ptags, pcosts, num_active, miss_latency, quanta, schedule,
         miss_latency, quanta, schedule, handler, bs_miss_extra, seed,
         num_tags=num_tags, total_steps=total_steps, window=window,
         pos_base=num_tags if seeded else 0, materialise=bool(materialise),
-        interpret=interpret)
+        interpret=interpret, name="window_cell")
     tile, last = tiles[0, 0, 0, 0], last[0, 0, 0, 0]
     vec = tile[:5, :num_progs]
     return (last[:num_tags, 0], last[:num_tags, 1], vec[_CURSORS],

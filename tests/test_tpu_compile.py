@@ -69,6 +69,14 @@ def _fits_one_chip(compiled):
     return used
 
 
+def _kernel_names(compiled) -> list[str]:
+    """The HLO instruction names of the compiled program's Pallas
+    kernels (its `tpu_custom_call`s)."""
+    return [line.split(" = ")[0].split()[-1]
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def _grid_args(shape, sharding):
     b, p, n, _, q, k, l = shape
     return (_i32((b, p, n), sharding), _i32((b, p, n), sharding),
@@ -85,6 +93,9 @@ def test_window_grid_compiles(one_chip, shape):
                                   interpret=False)
     ).lower(*_grid_args(shape, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's own `name`, which the device trace's readers look for
+    names = _kernel_names(compiled)
+    assert names and all(n.startswith("%window_grid") for n in names), names
     _fits_one_chip(compiled)
 
 
@@ -104,6 +115,8 @@ def test_window_cell_compiles(one_chip, p, n, steps):
     ).lower(s(p, n), s(p, n), s(), s(), s(p), s(p), s(), s(),
             seed).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    names = _kernel_names(compiled)
+    assert names and all(n.startswith("%window_cell") for n in names), names
     _fits_one_chip(compiled)
 
 
