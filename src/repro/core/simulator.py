@@ -65,7 +65,7 @@ picks per call:
     the reference semantics, and the fallback for the one remaining
     stronghold: preempted runs with a cold bitstream cache (plus
     hand-crafted `FleetState`s no engine can seed from).  Its hot loop
-    pre-gathers the per-program (tag, hw-cost) streams once per call
+    looks up the per-program (tag, hw-cost) streams once per call
     (instead of a dependent double gather per step), fuses the
     disambiguator + bitstream lookups into one state update
     (`slots.lookup_fused`), and unrolls the scan body (`scan_unroll`).
@@ -100,7 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (isa, slots, stackdist, stackdist_cold,
+from repro.core import (isa, lookup, slots, stackdist, stackdist_cold,
                         stackdist_interleaved)
 from repro.core.traces import Mix, analytic_cpi  # re-export for callers
 
@@ -996,7 +996,7 @@ def _fleet_step_fn(ptags, pcosts, miss_latency, active_slots, quanta,
                    schedule, handler, bs_miss_extra):
     """Round-robin step over precomputed per-program (tag, cost) streams.
 
-    `ptags`/`pcosts` are the (P, N) gathers `tags[p, traces[p, i]]` /
+    `ptags`/`pcosts` are the (P, N) lookups `tags[p, traces[p, i]]` /
     `hw[traces[p, i]]` hoisted out of the step: the hot loop does two
     independent stream loads instead of a dependent double gather per cycle,
     and one fused disambiguator+bitstream update (`slots.lookup_fused`).
@@ -1068,11 +1068,11 @@ def _simulate_fleet_impl(traces, tag_table, miss_latency, active_slots,
     hw = jnp.asarray(isa.INSTR_HW_CYCLES, jnp.int32)
     tags = jnp.asarray(tag_table, jnp.int32)
     num_progs = traces.shape[0]
-    # hoist the per-step dependent double gather: precompute the per-program
+    # hoist the per-step dependent double lookup: precompute the per-program
     # tag and hw-cost streams once (the instruction id itself is only ever
     # used through these two tables)
-    ptags = jnp.take_along_axis(tags, traces, axis=1)
-    pcosts = hw[traces]
+    ptags = lookup.table_lookup(tags, traces)
+    pcosts = lookup.table_lookup(hw, traces)
 
     init = (init_fleet_state(num_progs, num_slots, bs_entries)
             if state is None else state)

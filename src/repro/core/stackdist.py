@@ -53,6 +53,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.lookup import pick_along_tags, small_bincount, table_lookup
+
 __all__ = [
     "DistanceProfile", "SweepGrid",
     "distance_profile", "misses_for_counts", "cycles_grid",
@@ -94,15 +96,14 @@ def _profile_one(tags: jnp.ndarray, costs: jnp.ndarray,
         [jnp.full((1, num_tags), -1, jnp.int32), last_pos[:-1]], axis=0)
 
     slotted = tags >= 0
-    safe = jnp.clip(tags, 0)  # clamp -1 so the gather below stays in-bounds
-    prev_self = jnp.take_along_axis(prev, safe[:, None], axis=1)[:, 0]
+    prev_self = pick_along_tags(prev, tags)   # 0 where unslotted
     cold = slotted & (prev_self < 0)
     # distinct tags touched after my previous occurrence (excludes myself:
     # prev[i, tags[i]] == prev_self, never strictly greater)
     dist = jnp.sum(prev > prev_self[:, None], axis=1).astype(jnp.int32)
 
     bucket = jnp.where(slotted & ~cold, dist, jnp.int32(num_tags))
-    hist = jnp.bincount(bucket, length=num_tags + 1)[:num_tags]
+    hist = small_bincount(bucket, num_tags + 1)[:num_tags]
     return DistanceProfile(
         hist=hist.astype(jnp.int32),
         cold=jnp.sum(cold).astype(jnp.int32),
@@ -146,12 +147,13 @@ def cycles_grid(profile: DistanceProfile, slot_counts: jnp.ndarray,
 def _stream(traces: jnp.ndarray, instr_tag: jnp.ndarray,
             instr_costs: jnp.ndarray, total_steps: int):
     """Unroll (…, N) instruction traces into (…, total_steps) tag/cost
-    streams, wrapping the cursor exactly like the scan path does."""
-    idx = jnp.remainder(jnp.arange(total_steps, dtype=jnp.int32),
-                        traces.shape[-1])
-    stream = traces[..., idx]
-    return (jnp.asarray(instr_tag, jnp.int32)[stream],
-            jnp.asarray(instr_costs, jnp.int32)[stream])
+    streams, wrapping the cursor exactly like the scan path does (the
+    trace repeated end to end, cut at `total_steps`)."""
+    reps = -(-total_steps // traces.shape[-1])
+    stream = jnp.tile(traces, (1,) * (traces.ndim - 1) + (reps,))
+    stream = stream[..., :total_steps]
+    return (table_lookup(jnp.asarray(instr_tag, jnp.int32), stream),
+            table_lookup(jnp.asarray(instr_costs, jnp.int32), stream))
 
 
 @functools.partial(jax.jit, static_argnames=("num_tags", "total_steps"))

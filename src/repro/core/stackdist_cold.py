@@ -51,6 +51,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.lookup import pick_along_tags, small_bincount
 from repro.core.stackdist import _stream
 
 __all__ = ["ColdGrid", "lanes_cold", "sweep_cold"]
@@ -79,8 +80,7 @@ def _cold_one(tags: jnp.ndarray, costs: jnp.ndarray,
     prev = jnp.concatenate(
         [jnp.full((1, num_tags), -1, jnp.int32), last_pos[:-1]], axis=0)
     slotted = tags >= 0
-    safe = jnp.clip(tags, 0)   # clamp -1 so the gather stays in-bounds
-    prev_self = jnp.take_along_axis(prev, safe[:, None], axis=1)[:, 0]
+    prev_self = pick_along_tags(prev, tags)   # 0 where unslotted
     cold = slotted & (prev_self < 0)
     dist = jnp.sum(prev > prev_self[:, None], axis=1).astype(jnp.int32)
 
@@ -93,11 +93,11 @@ def _cold_one(tags: jnp.ndarray, costs: jnp.ndarray,
             axis=0)
         prev2 = jnp.concatenate(
             [jnp.full((1, num_tags), -1, jnp.int32), cm2[:-1]], axis=0)
-        prev2_self = jnp.take_along_axis(prev2, safe[:, None], axis=1)[:, 0]
+        prev2_self = pick_along_tags(prev2, tags)
         dist2 = jnp.sum(prev2 > prev2_self[:, None], axis=1).astype(jnp.int32)
         reuse = miss & (prev2_self >= 0)
         bucket = jnp.where(reuse, dist2, jnp.int32(num_tags))
-        hist2 = jnp.bincount(bucket, length=num_tags + 1)[:num_tags]
+        hist2 = small_bincount(bucket, num_tags + 1)[:num_tags]
         return jnp.sum(miss).astype(jnp.int32), hist2.astype(jnp.int32)
 
     slot_misses, hist2 = jax.vmap(per_count)(

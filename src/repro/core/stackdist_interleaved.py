@@ -104,6 +104,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.lookup import pick_along_tags, table_lookup
 from repro.kernels import window_distance
 
 __all__ = ["CellCarry", "InterleavedGrid", "resume_preempted",
@@ -149,7 +150,7 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
                    total_steps: int, window: int,
                    seed: CellCarry | None = None,
                    materialise: bool = False):
-    """One grid cell: (P, N) pre-gathered tag/cost streams -> counters.
+    """One grid cell: (P, N) looked-up tag/cost streams -> counters.
 
     Mirrors `simulator._fleet_step_fn`'s cost model exactly, one window
     per iteration instead of one access per scan step.  `num_active`,
@@ -193,8 +194,7 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
         prev = jnp.concatenate(
             [c.last_pos[None, :],
              jnp.maximum(cm[:-1], c.last_pos[None, :])], axis=0)
-        safe = jnp.clip(w_tags, 0)   # clamp -1 so the gather stays in-bounds
-        prev_self = jnp.take_along_axis(prev, safe[:, None], axis=1)[:, 0]
+        prev_self = pick_along_tags(prev, w_tags)   # 0 where unslotted
         cold = slotted & (prev_self < 0)
         dist = jnp.sum(prev > prev_self[:, None], axis=1).astype(jnp.int32)
         miss = slotted & (cold | (dist >= num_active))
@@ -276,8 +276,8 @@ def _resume_impl(fleet, tag_table, instr_costs, num_active, miss_latency,
     table = jnp.asarray(tag_table, jnp.int32)
     costs = jnp.asarray(instr_costs, jnp.int32)
     fleet = jnp.asarray(fleet, jnp.int32)
-    ptags = jnp.take_along_axis(table, fleet, axis=1)
-    pcosts = costs[fleet]
+    ptags = table_lookup(table, fleet)
+    pcosts = table_lookup(costs, fleet)
     if kernel:
         kseed = (seed.last_pos, seed.cursors, seed.sched_idx,
                  seed.q_cycles, seed.cycles, seed.instrs, seed.misses,
@@ -329,10 +329,10 @@ def _sweep_impl(fleets, tag_table, instr_costs, slot_counts,
     table = jnp.asarray(tag_table, jnp.int32)
     costs = jnp.asarray(instr_costs, jnp.int32)
     fleets = jnp.asarray(fleets, jnp.int32)
-    # hoist the per-access dependent double gather out of the loop, like
+    # hoist the per-access dependent double lookup out of the loop, like
     # the scan path does: (B, P, N) tag and hw-cost streams
-    ptags = jax.vmap(lambda f: jnp.take_along_axis(table, f, axis=1))(fleets)
-    pcosts = costs[fleets]
+    ptags = table_lookup(table, fleets)
+    pcosts = table_lookup(costs, fleets)
     if kernel:
         return InterleavedGrid(*window_distance.window_grid(
             ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,

@@ -11,7 +11,10 @@ compile, without a chip, at the shapes the benchmarks and
   fleet-scale shape (N=768, ~8 tenants per core);
 * the jnp `_sweep_impl` at the Fig. 7 grid, which must fit one chip;
 * the fleet-axis mesh sweep (`simulator._mesh_sweep_preempted`) over
-  four chips, running the kernel on each.
+  four chips, running the kernel on each;
+* the Fig. 7 kernel sweep and the stacked cold pass at the bitstream
+  grid, which must hold no element gather or scatter over a stream:
+  their lookups go through `repro.core.lookup`.
 
 The kernel entry points are compiled directly with `interpret=False`:
 `sweep_fleet` asks the running backend, which is the CPU here.  The
@@ -19,6 +22,7 @@ topology is described inside a fixture, never at import, so every
 pytest-xdist worker collects the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
 from repro.core import isa, simulator
+from repro.core import stackdist_cold as sdc
 from repro.core import stackdist_interleaved as sdi
 from repro.kernels import window_distance as wd
 
@@ -39,6 +44,12 @@ NUM_TAGS = int(np.max(simulator.fleet_tag_table(isa.SCENARIO_2, 2))) + 1
 #  quantum cells, slot counts, latencies) of each sweep grid
 FIG7 = (52, 2, 60_000, 160_000, 2, 3, 1)
 P4_FLEETS = (24, 4, 60_000, 240_000, 1, 1, 3)
+# (traces, trace length = steps, slot counts, latencies, capacities,
+#  penalties) of the bitstream study's grid
+BITSTREAM = (5, 100_000, 1, 1, 4, 2)
+# the most elements a gather or scatter may move: tables and histograms,
+# never a stream
+SMALL_GATHER = 4096
 
 
 @pytest.fixture(scope="module")
@@ -156,3 +167,76 @@ def test_fleet_mesh_sweep_compiles_for_four_chips(topo, monkeypatch):
     ).lower(part).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _fits_one_chip(compiled) > 0
+
+
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = \w+\[([\d,]*)\]")
+
+
+def _big_gathers_and_scatters(text: str) -> list[str]:
+    """The gathers and scatters of an HLO module, fused ones included,
+    that move more than `SMALL_GATHER` elements: a gather's output, a
+    scatter's output or updates (its third operand)."""
+    size = {}
+    for line in text.splitlines():
+        m = _DEF.match(line)
+        if m:
+            size[m.group(1)] = int(np.prod(
+                [int(d) for d in m.group(2).split(",") if d]))
+    big = []
+    for line in text.splitlines():
+        op = re.search(r" (gather|scatter)\((.*?)\)", line)
+        m = _DEF.match(line)
+        if not (op and m):
+            continue
+        moved = size[m.group(1)]
+        if op.group(1) == "scatter":
+            operands = re.findall(r"%[\w.\-]+", op.group(2))
+            moved = max(moved, size.get(operands[2], 0))
+        if moved > SMALL_GATHER:
+            big.append(line.strip()[:200])
+    return big
+
+
+def test_big_gather_detector_sees_stream_gathers(one_chip):
+    """The check below can fail: an opcode-table gather over a stream and
+    a histogram scatter are both caught."""
+    s = lambda *shape: _i32(shape, one_chip)  # noqa: E731
+    # (XLA itself turns a gather from a small table by a 1-D index into
+    # selects, so the index here is 2-D, as the engines' streams are)
+    compiled = jax.jit(
+        lambda t, x: (t[x], jnp.bincount(x.reshape(-1), length=11))
+    ).lower(s(isa.NUM_INSTRUCTIONS), s(5, 100_000)).compile()
+    big = _big_gathers_and_scatters(compiled.as_text())
+    assert any(" gather(" in b for b in big), big
+    assert any(" scatter(" in b for b in big), big
+
+
+def test_fig7_kernel_sweep_has_no_stream_gathers(one_chip):
+    """The opcode->tag and opcode->cost lookups of `_sweep_impl` compile
+    to elementwise selects, beside the window kernel."""
+    b, p, n, steps, q, k, l = FIG7
+    s = lambda *shape: _i32(shape, one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda *a: sdi._sweep_impl(*a, num_tags=NUM_TAGS,
+                                   total_steps=steps, window=WINDOW,
+                                   kernel=True, interpret=False)
+    ).lower(s(b, p, n), s(p, isa.NUM_INSTRUCTIONS), s(isa.NUM_INSTRUCTIONS),
+            s(k), s(l), s(q, p), s(p), s(), s()).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _big_gathers_and_scatters(text) == []
+
+
+def test_cold_pass_has_no_stream_gathers(one_chip):
+    """`sweep_cold` at the bitstream grid: the stream wrap, the opcode
+    lookups, the tag-axis picks and the distance histogram hold no
+    gather or scatter over the (traces, steps) streams."""
+    b, n, k, l, e, x = BITSTREAM
+    num_tags = isa.SCENARIO_2.num_tags
+    s = lambda *shape: _i32(shape, one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda *a: sdc.sweep_cold(*a, num_tags=num_tags, total_steps=n)
+    ).lower(s(b, n), s(isa.NUM_INSTRUCTIONS), s(isa.NUM_INSTRUCTIONS),
+            s(k), s(l), s(e), s(x)).compile()
+    assert _big_gathers_and_scatters(compiled.as_text()) == []
+    _fits_one_chip(compiled)
