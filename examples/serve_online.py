@@ -93,7 +93,7 @@ def main():
     tr = np.stack([np.asarray(model.trace(b)) for b in benches])
     scens = [model.scenario_of(b) for b in benches]
     st = simulator.init_fleet_state(len(benches), PCFG.num_slots,
-                                    OCFG.bs_cache_entries)
+                                    PCFG.bs_cache_entries)
     for epoch in range(3):
         with engine_log() as calls:
             res, st = simulator.simulate_many(

@@ -45,14 +45,17 @@ picks per call:
     instead each cell replays its interleaving at *scheduler-window*
     granularity — one vectorized Mattson cummax pass per window, a
     `lax.while_loop` whose trip count is ~steps/window + one per context
-    switch instead of one per step.  Exact (bit-for-bit) iff the bitstream
-    cache is warm over the FLEET's merged tag set and no int32 accumulator
-    can overflow (`interleaved_eligible`); ~15x over the optimized scan on
-    preempted fig6-style grids (BENCH_sweep.json).  The engine is also
-    *resumable*: a scan-shaped `FleetState` seeds it (cache contents map
-    to virtual merged-stream positions, the open quantum / scheduler
-    cursor / counters seed the loop carry) and a `FleetState`
-    materialises back out, bit-for-bit equal to the scan's.
+    switch instead of one per step.  Exact (bit-for-bit) iff costs are
+    non-negative and no int32 accumulator can overflow
+    (`interleaved_eligible`); a bitstream cache smaller than the FLEET's
+    merged tag set adds a second LRU distance per window, within the
+    slot-miss stream (the bitstream level); ~15x over the optimized scan
+    on preempted fig6-style grids (BENCH_sweep.json).  The engine is also
+    *resumable* with a warm bitstream cache: a scan-shaped `FleetState`
+    seeds it (cache contents map to virtual merged-stream positions, the
+    open quantum / scheduler cursor / counters seed the loop carry) and
+    a `FleetState` materialises back out, bit-for-bit equal to the
+    scan's.
   * **stacked cold-bitstream path** (`repro.core.stackdist_cold`): for
     *unpreempted* runs whose bitstream cache is undersized, the
     disambiguator's miss subsequence is itself an LRU reference stream, so
@@ -62,9 +65,10 @@ picks per call:
     (`sweep_bitstream` exposes the full capacity x penalty grid in one
     call).
   * **`lax.scan` path**: the general cycle-by-cycle round-robin machine —
-    the reference semantics, and the fallback for the one remaining
-    stronghold: preempted runs with a cold bitstream cache (plus
-    hand-crafted `FleetState`s no engine can seed from).  Its hot loop
+    the reference semantics, and the fallback for what is left: resumed
+    runs with a cold bitstream cache, masked (degraded) disambiguators,
+    sub-threshold quanta under `path="auto"`, and hand-crafted
+    `FleetState`s no engine can seed from.  Its hot loop
     looks up the per-program (tag, hw-cost) streams once per call
     (instead of a dependent double gather per step), fuses the
     disambiguator + bitstream lookups into one state update
@@ -102,6 +106,7 @@ import numpy as np
 
 from repro.core import (isa, lookup, slots, stackdist, stackdist_cold,
                         stackdist_interleaved)
+from repro.kernels import window_distance
 from repro.core.traces import Mix, analytic_cpi  # re-export for callers
 
 __all__ = [
@@ -308,9 +313,10 @@ def stackdist_cold_eligible(*, quantum_cycles, max_miss_latency: int,
     *dropped* — the second Mattson pass over the disambiguator's miss
     subsequence serves ANY bitstream capacity exactly, so an undersized
     (cold) bitstream cache no longer forces the scan as long as the run
-    is unpreempted (preempted + cold remains the scan's last stronghold:
-    there the miss subsequence itself is switch-point-dependent per grid
-    cell AND the bitstream axis feeds back into the switch points).
+    is unpreempted (a preempted cold run's miss subsequence is
+    switch-point-dependent per grid cell and its bitstream misses feed
+    back into the switch points: the interleaved engine's bitstream
+    level replays those per window instead).
     """
     worst_step = (int(np.max(isa.INSTR_HW_CYCLES)) + int(max_miss_latency)
                   + int(bs_miss_extra))
@@ -319,47 +325,42 @@ def stackdist_cold_eligible(*, quantum_cycles, max_miss_latency: int,
             and total_steps * worst_step < min_quantum)
 
 
-def interleaved_eligible(tag_table, *, bs_entries: int, miss_latencies,
-                         bs_miss_extra: int, handler_cycles: int,
-                         total_steps: int) -> bool:
+def interleaved_eligible(*, miss_latencies, bs_miss_extra: int,
+                         handler_cycles: int, total_steps: int) -> bool:
     """True iff the interleave-aware fast path is *exact* for this run.
 
     Gates `repro.core.stackdist_interleaved`, which serves preempted (and
     mixed preempted/unpreempted) one-shot runs.  Unlike
     `stackdist_eligible` there is no quantum condition at all: every grid
     cell replays its own switch points, so any quantum — uniform,
-    per-program, swept, even unreachable — is exact.  What remains:
+    per-program, swept, even unreachable — is exact.  Nor is there a
+    bitstream-cache condition: a cache smaller than the fleet's merged
+    tag set (the caches are shared, so the union counts) runs the window
+    pass's bitstream level, a second LRU distance within the slot-miss
+    stream (`stackdist_interleaved.bs_level`).  What remains:
 
-    1. warm bitstream cache over the *fleet*: `bs_entries` covers the
-       merged tag alphabet (`tag_table` is the (P, num_opcodes) per-program
-       table; the caches are shared, so the union matters — a fleet whose
-       second program slots more opcodes than its first can be cold even
-       when program 0 alone would be warm).  Warm means a bitstream miss
-       happens exactly on each tag's first touch in the merged stream,
-       decoupling the bitstream axis from the slot-count axis;
-    2. non-negative costs: latencies / bitstream penalty / handler >= 0,
+    1. non-negative costs: latencies / bitstream penalty / handler >= 0,
        so the in-window cycle accumulation is monotone;
-    3. no-overflow guard: worst-case per-access cost plus a handler every
-       access, summed over `total_steps`, stays inside int32 — the same
+    2. no-overflow guard: worst-case per-access cost (a slot miss and a
+       bitstream miss on every access) plus a handler every access,
+       summed over `total_steps`, stays inside int32 — the same
        accumulators the scan uses.
 
-    Resumed (`state=`) runs are eligible too: the engine seeds from a
-    `FleetState` (see `repro.core.stackdist_interleaved.resume_preempted`)
-    provided the state is scan-shaped (`_seedable_fleet_state`: prefix
-    packing, distinct LRU clocks, slot residents covered by the bitstream
-    cache) and the seed's counters leave int32 headroom for the segment —
-    `simulate_many` checks both on top of this predicate and falls back
-    to the scan for hand-crafted states that fail them.
+    Resumed (`state=`) runs need more: the resumable engine models a
+    warm bitstream cache only, so `simulate_many` also asks for
+    `bs_entries` to cover the merged tag set, and for a scan-shaped
+    `FleetState` (`_seedable_fleet_state`: prefix packing, distinct LRU
+    clocks, slot residents covered by the bitstream cache) whose counters
+    leave int32 headroom for the segment; it falls back to the scan for
+    states that fail them.
     """
-    num_tags = int(np.max(tag_table)) + 1
-    warm = bs_entries >= num_tags
     lats = np.asarray(miss_latencies)
     nonneg = (int(np.min(lats)) >= 0 and int(bs_miss_extra) >= 0
               and int(handler_cycles) >= 0)
     worst_step = (int(np.max(isa.INSTR_HW_CYCLES)) + int(np.max(lats))
                   + int(bs_miss_extra) + int(handler_cycles))
     no_overflow = total_steps * worst_step < np.iinfo(np.int32).max
-    return warm and nonneg and no_overflow
+    return nonneg and no_overflow
 
 
 # auto-dispatch heuristics for the interleaved engine (forcing
@@ -429,10 +430,8 @@ def _check_path(path: str, stackdist_ok: bool, interleaved_ok: bool = False,
             "int32-safe costs (see simulator.stackdist_cold_eligible)")
     if path == "interleaved" and not interleaved_ok:
         raise ValueError(
-            "interleaved path requires a one-shot run with a warm "
-            "bitstream cache over the fleet's merged tag set and "
-            "non-negative int32-safe costs (see "
-            "simulator.interleaved_eligible)")
+            "interleaved path requires non-negative int32-safe costs "
+            "(see simulator.interleaved_eligible)")
     if path == "auto":
         path = ("stackdist" if stackdist_ok
                 else "stackdist_cold" if cold_ok
@@ -1111,14 +1110,17 @@ def simulate_many(traces: np.ndarray, cfg: ReconfigConfig,
     (results, S')".  A run split at any step boundary reproduces the
     one-shot run bit-for-bit (counters are cumulative in the state).
 
-    Dispatch: calls with a warm bitstream cache — one-shot, resumed
-    (`state=`), or `return_state=True` — route through the
+    Dispatch: one-shot result-only calls route through the
     interleave-aware fast path (`repro.core.stackdist_interleaved`),
-    preempted or not, and are bit-for-bit equal to the scan: the engine
-    seeds from the `FleetState` (a one-shot `return_state` run seeds from
-    the cold init state) and materialises the final state back out in
-    canonical form.  Hand-crafted states no scan could produce
-    (`_seedable_fleet_state`), cold bitstream caches, and sub-threshold
+    preempted or not, whatever the bitstream cache's size (a cache
+    smaller than the merged tag set runs the window pass's bitstream
+    level); resumed (`state=`) and `return_state=True` calls with a warm
+    bitstream cache ride its resumable entry.  Both are bit-for-bit equal
+    to the scan: the resumable engine seeds from the `FleetState` (a
+    one-shot `return_state` run seeds from the cold init state) and
+    materialises the final state back out in canonical form.
+    Hand-crafted states no scan could produce (`_seedable_fleet_state`),
+    state-carrying runs with a cold bitstream cache, and sub-threshold
     quanta fall back to the cycle-by-cycle scan, whose returned states
     are canonicalised too (`_canonical_state` — behaviour-preserving, so
     resumes and state comparisons never see which engine ran).
@@ -1180,15 +1182,14 @@ def simulate_many(traces: np.ndarray, cfg: ReconfigConfig,
                 f"whose priority weights produce a schedule at least as "
                 f"long as the one the state was built under")
     eligible = interleaved_eligible(
-        table, bs_entries=cfg.bs_cache_entries,
-        miss_latencies=[cfg.miss_latency], bs_miss_extra=cfg.bs_miss_extra,
+        miss_latencies=[cfg.miss_latency],
+        bs_miss_extra=cfg.bs_miss_extra,
         handler_cycles=sched.handler_cycles, total_steps=total_steps)
     if state is None and not return_state:
         # one-shot result-only: no state to seed or materialise
         if path == "interleaved" and not eligible:
             raise ValueError(
-                "interleaved path requires a warm bitstream cache over the "
-                "fleet's merged tag set and non-negative int32-safe costs "
+                "interleaved path requires non-negative int32-safe costs "
                 "(see simulator.interleaved_eligible)")
         if path == "interleaved" or (
                 path == "auto" and not masked and eligible
@@ -1200,7 +1201,8 @@ def simulate_many(traces: np.ndarray, cfg: ReconfigConfig,
                 jnp.asarray([cfg.miss_latency], jnp.int32),
                 jnp.asarray([cfg.num_slots], jnp.int32), quanta[None, :],
                 schedule, sched.handler_cycles, cfg.bs_miss_extra,
-                total_steps, None, use_kernel)
+                total_steps, None, use_kernel,
+                bs_entries=cfg.bs_cache_entries)
             return FleetResult(*(x[0, 0, 0, 0] for x in res))
     else:
         # state-carrying: seed the resumable engine from the given state
@@ -1254,15 +1256,23 @@ def simulate_many(traces: np.ndarray, cfg: ReconfigConfig,
 _span = jax.profiler.TraceAnnotation
 
 # grid cells the sweep engines were asked for, and the cells they were
-# launched over (padded fleet rows included); see `cell_counts`
+# launched over (padded fleet rows included), in all and by engine; see
+# `cell_counts`
 _cells_real = 0
 _cells_launched = 0
+_ENGINES = ("interleaved", "stackdist", "stackdist_cold", "scan")
+_cells_by_engine = dict.fromkeys(
+    [f"cells_{e}" for e in _ENGINES] + ["cells_bs_level"], 0)
 
 
-def _count_cells(real: int, launched: int) -> None:
+def _count_cells(real: int, launched: int, engine: str,
+                 bs_level: bool = False) -> None:
     global _cells_real, _cells_launched
     _cells_real += int(real)
     _cells_launched += int(launched)
+    _cells_by_engine[f"cells_{engine}"] += int(launched)
+    if bs_level:
+        _cells_by_engine["cells_bs_level"] += int(launched)
 
 
 def cell_counts() -> dict:
@@ -1270,8 +1280,13 @@ def cell_counts() -> dict:
     launched over (`cells_launched`: fleet rows the interleaved engine
     pads to its batch bucket or the mesh count their cells too), summed
     over the process's `sweep_fleet` and `sweep_bitstream` calls and
-    `simulate_many`'s one-shot interleaved runs."""
-    return {"cells_real": _cells_real, "cells_launched": _cells_launched}
+    `simulate_many`'s one-shot interleaved runs.  The launched cells are
+    also counted by the engine that ran them (`cells_interleaved`,
+    `cells_stackdist`, `cells_stackdist_cold`, `cells_scan`), and
+    `cells_bs_level` counts the interleaved cells whose window pass ran
+    the bitstream level (a cache smaller than the merged tag set)."""
+    return {"cells_real": _cells_real, "cells_launched": _cells_launched,
+            **_cells_by_engine}
 
 
 @functools.partial(
@@ -1397,32 +1412,57 @@ def fleet_mesh_size() -> int:
 
 def _mesh_sweep_preempted(mesh, part, table, counts, lats, quanta_grid,
                           schedule, handler, bs_miss_extra, num_tags: int,
-                          total_steps: int, w: int, use_kernel):
+                          total_steps: int, w: int, use_kernel,
+                          bs_entries: int | None = None):
     """Shard one padded fleet chunk across the device mesh: each device
     runs the interleaved sweep (jnp or Pallas-kernel window pass alike)
-    over its fleet shard; grid/scalar operands replicate via closure.
-    Results concatenate along the fleet axis, so this is bit-identical
-    to the single-device call on the same chunk."""
+    over its fleet shard; grid/scalar operands replicate.  Results
+    concatenate along the fleet axis, so this is bit-identical to the
+    single-device call on the same chunk.  The sharded program is one
+    jitted function of the mesh and the static sizes, so a repeat call
+    of the same shapes compiles nothing."""
+    kernel, interpret = window_distance.resolve(use_kernel)
+    return _mesh_sweep_impl(
+        part, jnp.asarray(table, jnp.int32),
+        jnp.asarray(isa.INSTR_HW_CYCLES, jnp.int32), counts, lats,
+        jnp.asarray(quanta_grid, jnp.int32),
+        jnp.asarray(schedule, jnp.int32), jnp.int32(handler),
+        jnp.int32(bs_miss_extra), mesh=mesh, num_tags=num_tags,
+        total_steps=total_steps, window=w, kernel=kernel,
+        interpret=interpret,
+        bs_entries=stackdist_interleaved.bs_level(bs_entries, num_tags))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "num_tags", "total_steps", "window", "kernel", "interpret",
+    "bs_entries"))
+def _mesh_sweep_impl(part, table, costs, counts, lats, quanta, schedule,
+                     handler, bs_miss_extra, *, mesh, num_tags: int,
+                     total_steps: int, window: int, kernel: bool,
+                     interpret: bool, bs_entries: int | None):
     spec = jax.sharding.PartitionSpec
 
-    def shard(pt):
-        return stackdist_interleaved.sweep_preempted(
-            pt, table, isa.INSTR_HW_CYCLES, counts, lats,
-            jnp.asarray(quanta_grid, jnp.int32),
-            jnp.asarray(schedule, jnp.int32), jnp.int32(handler),
-            jnp.int32(bs_miss_extra), num_tags=num_tags,
-            total_steps=total_steps, window=w, use_kernel=use_kernel)
+    def shard(pt, *operands):
+        return stackdist_interleaved._sweep_impl(
+            pt, *operands, num_tags=num_tags, total_steps=total_steps,
+            window=window, kernel=kernel, interpret=interpret,
+            bs_entries=bs_entries)
 
+    operands = (table, costs, counts, lats, quanta, schedule, handler,
+                bs_miss_extra)
     out_specs = stackdist_interleaved.InterleavedGrid(
         *([spec(None, "fleet")] * 5))
-    return jax.shard_map(shard, mesh=mesh, in_specs=(spec("fleet"),),
-                         out_specs=out_specs, check_vma=False)(part)
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(spec("fleet"),) + (spec(),) * len(operands),
+        out_specs=out_specs, check_vma=False)(part, *operands)
 
 
 def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
                              schedule, handler, bs_miss_extra,
                              total_steps: int, window: int | None,
-                             use_kernel=None) -> FleetResult:
+                             use_kernel=None,
+                             bs_entries: int | None = None) -> FleetResult:
     """Serve the full (Q, B, K, L) grid from the interleave-aware engine.
 
     Each cell replays its own switch points (they are cost-dependent), so
@@ -1438,16 +1478,19 @@ def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
     independent, so sharding the batch is exact; padding rounds up to
     the device count and padded rows are sliced off as before.
     `use_kernel` picks the window-pass implementation
-    (`repro.kernels.window_distance.resolve`).
+    (`repro.kernels.window_distance.resolve`); `bs_entries` is the
+    bitstream cache's capacity, which below the merged tag set's size
+    compiles the window pass's bitstream level in (None: warm).
     """
     num_tags = max(int(np.max(np.asarray(table))) + 1, 1)
+    level = stackdist_interleaved.bs_level(bs_entries, num_tags) is not None
     w = _interleaved_window(quanta_grid, total_steps, window)
     cells = quanta_grid.shape[0] * counts.shape[0] * lats.shape[0]
     chunk = max(1, _INTERLEAVED_CHUNK_ELEMS // max(w * num_tags * cells, 1))
     b_total = fleets.shape[0]
     mesh = _fleet_mesh()
     ndev = mesh.devices.size if mesh is not None else 1
-    grids = []
+    grids, reals = [], []
     for i in range(0, b_total, chunk):
         with _span("sim.stage"):
             part = jnp.asarray(fleets[i:i + chunk])
@@ -1458,19 +1501,20 @@ def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
                              * _INTERLEAVED_BATCH_BUCKET, chunk)
             target = -(-target // ndev) * ndev  # mesh: divisible shards
             real = part.shape[0]
+            reals.append(real)
             pad = target - real
             if pad > 0:
                 part = jnp.concatenate(
                     [part, jnp.broadcast_to(part[:1],
                                             (pad,) + part.shape[1:])],
                     axis=0)
-        _count_cells(cells * real, cells * target)
+        _count_cells(cells * real, cells * target, "interleaved", level)
         with _span("sim.launch"):
             if mesh is not None:
                 grids.append(_mesh_sweep_preempted(
                     mesh, part, table, counts, lats, quanta_grid, schedule,
                     handler, bs_miss_extra, num_tags, total_steps, w,
-                    use_kernel))
+                    use_kernel, bs_entries))
             else:
                 grids.append(stackdist_interleaved.sweep_preempted(
                     part, table, isa.INSTR_HW_CYCLES, counts, lats,
@@ -1478,11 +1522,13 @@ def _sweep_fleet_interleaved(fleets, table, lats, counts, quanta_grid,
                     jnp.asarray(schedule, jnp.int32), jnp.int32(handler),
                     jnp.int32(bs_miss_extra), num_tags=num_tags,
                     total_steps=total_steps, window=w,
-                    use_kernel=use_kernel))
+                    bs_entries=bs_entries, use_kernel=use_kernel))
     with _span("sim.assemble"):
-        return FleetResult(*(jnp.concatenate([g[f] for g in grids],
-                                             axis=1)[:, :b_total]
-                             for f in range(5)))
+        # every chunk drops its own padded rows: on a mesh each chunk,
+        # not only the tail, is padded to a multiple of the device count
+        return FleetResult(*(jnp.concatenate(
+            [g[f][:, :r] for g, r in zip(grids, reals)], axis=1)
+            for f in range(5)))
 
 
 @functools.partial(jax.profiler.annotate_function, name="sim.sweep_fleet")
@@ -1509,14 +1555,16 @@ def sweep_fleet(fleets: np.ndarray, miss_latencies, scenarios,
     then identical by construction and broadcast); unpreempted grids with
     a COLD bitstream cache take the stacked pass
     (`stackdist_cold_eligible` / `repro.core.stackdist_cold`) instead of
-    the scan; preempted or mixed grids with a fleet-warm bitstream cache
-    (`interleaved_eligible`) replay every cell's own interleaving at
-    scheduler-window granularity (`repro.core.stackdist_interleaved`;
+    the scan; preempted or mixed grids (`interleaved_eligible`) replay
+    every cell's own interleaving at scheduler-window granularity, with
+    the bitstream level compiled in where `bs_cache_entries` is below the
+    fleet's merged tag set (`repro.core.stackdist_interleaved`;
     `interleave_window` overrides the tuned backend-aware window size and
     `use_kernel` the window-pass implementation — jnp body or fused
     Pallas kernel, see `repro.kernels.window_distance.resolve` — results
-    identical for any value of either); everything else — now only preempted runs
-    with cold bitstream caches — runs the jitted vmap^4 of `lax.scan`s,
+    identical for any value of either); everything else — negative or
+    int32-unsafe costs, and quanta below the auto floor unless the engine
+    is forced — runs the jitted vmap^4 of `lax.scan`s,
     where slot counts sweep by masking one max-size disambiguator
     (`slots.lookup`'s `num_active`).  `path` forces a specific engine
     ("stackdist"/"stackdist_cold"/"interleaved" raise if the grid is
@@ -1553,8 +1601,7 @@ def sweep_fleet(fleets: np.ndarray, miss_latencies, scenarios,
             max_miss_latency=int(np.max(np.asarray(miss_latencies))),
             bs_miss_extra=bs_miss_extra, total_steps=total_steps)
         inter_eligible = interleaved_eligible(
-            table, bs_entries=bs_cache_entries, miss_latencies=lats,
-            bs_miss_extra=bs_miss_extra,
+            miss_latencies=lats, bs_miss_extra=bs_miss_extra,
             handler_cycles=sched.handler_cycles, total_steps=total_steps)
         grid_cells = quanta_grid.shape[0] * counts.shape[0] * lats.shape[0]
         inter_auto = _interleaved_auto_ok(
@@ -1569,12 +1616,12 @@ def sweep_fleet(fleets: np.ndarray, miss_latencies, scenarios,
         schedule = sched.schedule(num_progs)
     if chosen != "interleaved":     # which counts its padded chunks itself
         _count_cells(grid_cells * fleets.shape[0],
-                     grid_cells * fleets.shape[0])
+                     grid_cells * fleets.shape[0], chosen)
     if chosen == "interleaved":
         res = _sweep_fleet_interleaved(
             fleets, table, lats, counts, quanta_grid, schedule,
             sched.handler_cycles, bs_miss_extra, total_steps,
-            interleave_window, use_kernel)
+            interleave_window, use_kernel, bs_entries=bs_cache_entries)
     elif chosen == "scan":
         with _span("sim.stage"):
             s_max = int(np.max(np.asarray(slot_counts)))
@@ -1654,8 +1701,10 @@ def sweep_bitstream(traces: np.ndarray, scenario: isa.SlotScenario, *,
                 "simulator.stackdist_cold_eligible)")
     b = traces.shape[0]
     shape = (b, counts.size, lats.size, caps.size, extras.size)
-    _count_cells(np.prod(shape), np.prod(shape))
-    if path != "scan" and cold_ok:
+    fast = path != "scan" and cold_ok
+    _count_cells(np.prod(shape), np.prod(shape),
+                 "stackdist_cold" if fast else "scan")
+    if fast:
         with _span("sim.stage"):
             args = (jnp.asarray(counts), jnp.asarray(lats),
                     jnp.asarray(caps), jnp.asarray(extras))

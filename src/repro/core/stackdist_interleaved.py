@@ -44,18 +44,29 @@ sequential-depth-for-parallel-work trade that bought the unpreempted
 path its ~40x, now available in the preempted regime the serving stack
 (placement search, online re-placement pricing) actually lives in.
 
-Exactness needs the **warm bitstream cache** precondition for the same
-reason the unpreempted path does: warm (entries >= distinct tags across
-*every* program's tag table — the disambiguator and bitstream cache are
-shared, so tag streams merge) means the bitstream cache never evicts, a
-bitstream miss happens exactly on each tag's first (cold) touch in the
-merged stream, and the bitstream axis decouples from the slot-count
-axis.  Cold bitstream caches stay on the scan (preempted) or take the
-stacked pass of `repro.core.stackdist_cold` (unpreempted).  All
+**The bitstream level.**  With a warm bitstream cache (entries >=
+distinct tags across *every* program's tag table — the disambiguator
+and bitstream cache are shared, so tag streams merge) the cache never
+evicts and a bitstream miss happens exactly on each tag's first (cold)
+touch in the merged stream.  A smaller cache evicts, and what it evicts
+depends on the slot-miss stream, so on the slot count, the latency and
+the switch points.  It sees exactly that stream, though: a slot miss
+fetches through it and nothing else touches it.  So the window pass
+stacks a second Mattson pass on the first, as `repro.core.stackdist_cold`
+does for unpreempted runs, per window: the carry holds each tag's last
+slot-miss position (`last_miss_pos`), a cummax over the window's
+miss-masked occurrence matrix, floored with it, gives the bitstream
+cache's state before each access, and a slot miss is a bitstream miss
+when its tag never missed before or its distance within the miss stream
+(the tags that missed since) reaches the capacity.  Bitstream misses
+then cost `bs_miss_extra` in the window's cycle sum, so they move the
+switch points exactly as in the scan.  The level is static
+(`bs_level`): a warm cache compiles the pass without it.  All
 arithmetic is int32 like the scan, so eligible results are bit-for-bit
-identical (`repro.core.simulator.interleaved_eligible` guards warmth and
-int32 overflow; parity is enforced by
-tests/test_stackdist_interleaved.py).
+identical (`repro.core.simulator.interleaved_eligible` guards
+non-negative costs and int32 overflow; parity is enforced by
+tests/test_stackdist_interleaved.py and tests/test_bitstream_level.py).
+The resumable entry below models a warm cache only.
 
 **Resumable runs** (`resume_preempted`): a cell can also start from a
 scan `FleetState` instead of a cold stream.  The seed translates cache
@@ -149,7 +160,8 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
                    schedule, handler, bs_miss_extra, num_tags: int,
                    total_steps: int, window: int,
                    seed: CellCarry | None = None,
-                   materialise: bool = False):
+                   materialise: bool = False,
+                   bs_entries: int | None = None):
     """One grid cell: (P, N) looked-up tag/cost streams -> counters.
 
     Mirrors `simulator._fleet_step_fn`'s cost model exactly, one window
@@ -162,7 +174,10 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
     sit below every new access (see module docstring).  With
     `materialise` (static) the carry additionally tracks per-tag last
     slot-miss positions and the full final carry is returned instead of
-    the counter tuple.
+    the counter tuple.  With `bs_entries` (static, below `num_tags`) the
+    window pass adds the bitstream level (module docstring): the carry
+    tracks last slot-miss positions and a bitstream miss is a slot miss
+    whose distance within the miss stream reaches `bs_entries`.
     """
     num_progs, trace_len = ptags.shape
     tag_ids = jnp.arange(num_tags, dtype=jnp.int32)
@@ -199,10 +214,29 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
         dist = jnp.sum(prev > prev_self[:, None], axis=1).astype(jnp.int32)
         miss = slotted & (cold | (dist >= num_active))
 
-        # scan cost model: hw + miss latency + (warm bitstream cache ->
-        # bitstream miss exactly on the cold touch)
+        # the bitstream cache sees exactly the slot-miss stream: its state
+        # before each access is the miss stream's last occurrence per tag
+        track_miss = materialise or bs_entries is not None
+        if track_miss:
+            cm_miss = jax.lax.cummax(
+                jnp.where(match & miss[:, None], pos[:, None],
+                          jnp.int32(-1)), axis=0)
+        if bs_entries is None:
+            # warm bitstream cache: a bitstream miss is the cold touch
+            bs_miss = cold
+        else:
+            prev2 = jnp.concatenate(
+                [c.last_miss_pos[None, :],
+                 jnp.maximum(cm_miss[:-1], c.last_miss_pos[None, :])],
+                axis=0)
+            prev2_self = pick_along_tags(prev2, w_tags)
+            dist2 = jnp.sum(prev2 > prev2_self[:, None],
+                            axis=1).astype(jnp.int32)
+            bs_miss = miss & ((prev2_self < 0) | (dist2 >= bs_entries))
+
+        # scan cost model: hw + miss latency + bitstream penalty
         cost = (w_hw + jnp.where(miss, miss_latency, 0)
-                + jnp.where(cold, bs_miss_extra, 0)).astype(jnp.int32)
+                + jnp.where(bs_miss, bs_miss_extra, 0)).astype(jnp.int32)
         cum = c.q_cycles + jnp.cumsum(cost)
         expire = cum >= quanta[p]
         any_exp = jnp.any(expire)
@@ -216,10 +250,7 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
         do_switch = any_exp & (n_exp <= remaining)
 
         committed = jnp.take(cm, n - 1, axis=0)   # per-tag last occ <= n-1
-        if materialise:
-            cm_miss = jax.lax.cummax(
-                jnp.where(match & miss[:, None], pos[:, None],
-                          jnp.int32(-1)), axis=0)
+        if track_miss:
             last_miss_pos = jnp.maximum(c.last_miss_pos,
                                         jnp.take(cm_miss, n - 1, axis=0))
         else:
@@ -242,7 +273,7 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
             misses=c.misses.at[p].add(
                 jnp.sum(miss & in_run).astype(jnp.int32)),
             bs_misses=c.bs_misses.at[p].add(
-                jnp.sum(cold & in_run).astype(jnp.int32)),
+                jnp.sum(bs_miss & in_run).astype(jnp.int32)),
             switches=c.switches + do_switch.astype(jnp.int32),
         )
 
@@ -251,7 +282,8 @@ def _simulate_cell(ptags, pcosts, num_active, miss_latency, quanta,
         init = CellCarry(
             last_pos=jnp.full((num_tags,), -1, jnp.int32),
             last_miss_pos=(jnp.full((num_tags,), -1, jnp.int32)
-                           if materialise else None),
+                           if materialise or bs_entries is not None
+                           else None),
             cursors=zeros_p, sched_idx=jnp.int32(0), steps_done=jnp.int32(0),
             q_cycles=jnp.int32(0), cycles=zeros_p, instrs=zeros_p,
             misses=zeros_p, bs_misses=zeros_p, switches=jnp.int32(0))
@@ -321,11 +353,12 @@ def resume_preempted(fleet: jnp.ndarray, tag_table: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_tags", "total_steps", "window",
-                                    "kernel", "interpret"))
+                                    "kernel", "interpret", "bs_entries"))
 def _sweep_impl(fleets, tag_table, instr_costs, slot_counts,
                 miss_latencies, quanta, schedule, handler, bs_miss_extra,
                 *, num_tags: int, total_steps: int, window: int,
-                kernel: bool, interpret: bool) -> InterleavedGrid:
+                kernel: bool, interpret: bool,
+                bs_entries: int | None = None) -> InterleavedGrid:
     table = jnp.asarray(tag_table, jnp.int32)
     costs = jnp.asarray(instr_costs, jnp.int32)
     fleets = jnp.asarray(fleets, jnp.int32)
@@ -337,13 +370,15 @@ def _sweep_impl(fleets, tag_table, instr_costs, slot_counts,
         return InterleavedGrid(*window_distance.window_grid(
             ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
             handler, bs_miss_extra, num_tags=num_tags,
-            total_steps=total_steps, window=window, interpret=interpret))
+            total_steps=total_steps, window=window, interpret=interpret,
+            bs_entries=bs_entries))
 
     def one(pt, pc, s, lat, qv):
         return _simulate_cell(pt, pc, s, lat, qv, schedule,
                               jnp.asarray(handler, jnp.int32),
                               jnp.asarray(bs_miss_extra, jnp.int32),
-                              num_tags, total_steps, window)
+                              num_tags, total_steps, window,
+                              bs_entries=bs_entries)
 
     f = jax.vmap(one, in_axes=(None, None, None, 0, None))   # latency axis
     f = jax.vmap(f, in_axes=(None, None, 0, None, None))     # slot-count
@@ -360,6 +395,7 @@ def sweep_preempted(fleets: jnp.ndarray, tag_table: jnp.ndarray,
                     miss_latencies: jnp.ndarray, quanta: jnp.ndarray,
                     schedule: jnp.ndarray, handler, bs_miss_extra, *,
                     num_tags: int, total_steps: int, window: int,
+                    bs_entries: int | None = None,
                     use_kernel=None) -> InterleavedGrid:
     """Preempted-fleet sweep: (B, P, N) traces -> InterleavedGrid.
 
@@ -372,10 +408,23 @@ def sweep_preempted(fleets: jnp.ndarray, tag_table: jnp.ndarray,
     grid is a vmap^4 over one cell engine — or, under `use_kernel` (see
     module docstring), one fused Pallas kernel whose grid is the cell
     grid — axis order matching the scan's `simulator._sweep_fleet`.
+    `bs_entries` is the bitstream cache's capacity; below `num_tags` the
+    window pass runs the bitstream level (module docstring), otherwise
+    (or None) the cache is warm and the level is not compiled in.
     """
     kernel, interpret = window_distance.resolve(use_kernel)
     return _sweep_impl(fleets, tag_table, instr_costs, slot_counts,
                        miss_latencies, quanta, schedule, handler,
                        bs_miss_extra, num_tags=num_tags,
                        total_steps=total_steps, window=window,
-                       kernel=kernel, interpret=interpret)
+                       kernel=kernel, interpret=interpret,
+                       bs_entries=bs_level(bs_entries, num_tags))
+
+
+def bs_level(bs_entries: int | None, num_tags: int) -> int | None:
+    """The capacity the window pass models as a bitstream level: the
+    cache's entries when they cannot hold all `num_tags` tags, else None
+    (a warm cache misses exactly on each tag's cold touch)."""
+    if bs_entries is None or int(bs_entries) >= num_tags:
+        return None
+    return int(bs_entries)

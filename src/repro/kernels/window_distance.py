@@ -10,9 +10,12 @@ of those steps is its own HBM-round-trip over the (W, num_tags) `occ` /
 `cm` intermediates, multiplied by the vmap^4 grid.
 
 This module fuses the whole pass — last-occurrence update, stack
-distance, cold/miss classification, cost cumsum and quantum-expiry
-search — into ONE Pallas kernel.  The per-tag `last_pos` vector (and in
-materialise mode `last_miss_pos`) lives in vector registers as the
+distance, cold/miss classification, the bitstream level where the
+bitstream cache is smaller than the tag set (a second lane scan over the
+slot-miss positions, compiled in only then), cost cumsum and
+quantum-expiry search — into ONE Pallas kernel.  The per-tag `last_pos`
+vector (and in materialise mode or with the bitstream level
+`last_miss_pos`) lives in vector registers as the
 `while_loop` carry for the whole cell run, the per-program counters and
 cursors live in SMEM, and the (num_tags, W) matrices exist only as
 in-kernel values and never hit HBM.  Two entry points share one kernel:
@@ -158,11 +161,13 @@ _CURSORS, _CYCLES, _INSTRS, _MISSES, _BS_MISSES = range(5)
 def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
             tags_ref, costs_ref, seed_last_ref, out_ref, out_last_ref,
             cnt_ref, *, num_progs, trace_len, total_steps, window, w_pad,
-            pos_base, materialise):
+            pos_base, materialise, bs_entries):
     """One cell per grid step: `_simulate_cell`'s while-loop with every
     window intermediate kept on-chip.  `tags_ref`/`costs_ref` hold the
     fleet's (P, ext) wrapped streams; `seed_ref` is the flat SMEM seed
-    (five (P,) counter fields, then sched_idx, q_cycles, switches)."""
+    (five (P,) counter fields, then sched_idx, q_cycles, switches).
+    `bs_entries` (static, or None for a warm cache) adds the bitstream
+    level: a second lane scan over the slot-miss positions."""
     q, k, l = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     num_active = counts_ref[k]
     miss_latency = lats_ref[l]
@@ -210,8 +215,26 @@ def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
                        keepdims=True)
         miss = slotted & (cold | (dist >= num_active))
 
+        track_miss = materialise or bs_entries is not None
+        if track_miss:
+            cm_miss = _lane_scan(jnp.where(match & miss, pos, -1),
+                                 jnp.maximum, _INT_MIN)
+        if bs_entries is None:
+            bs_miss = cold
+        else:
+            # the bitstream cache's state before each access: the miss
+            # stream's previous lane floored with the carried last_miss
+            prev2 = jnp.maximum(
+                jnp.where(lane >= 1, pltpu.roll(cm_miss, 1, 1), -1),
+                last_miss)
+            prev2_self = jnp.sum(jnp.where(sel, prev2, 0), axis=0,
+                                 keepdims=True)
+            dist2 = jnp.sum((prev2 > prev2_self).astype(jnp.int32), axis=0,
+                            keepdims=True)
+            bs_miss = miss & ((prev2_self < 0) | (dist2 >= bs_entries))
+
         cost = (w_hw + jnp.where(miss, miss_latency, 0)
-                + jnp.where(cold, bs_extra, 0))
+                + jnp.where(bs_miss, bs_extra, 0))
         cum = q_cycles + _lane_scan(cost, jnp.add, 0)
         expire = cum >= quanta_ref[q * num_progs + p]
         # padded lanes repeat cum[window-1], so the first expiring lane is
@@ -227,9 +250,7 @@ def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
         committed = jnp.max(jnp.where(last_lane, cm, -1), axis=1,
                             keepdims=True)
         end_cum = jnp.sum(jnp.where(last_lane, cum, 0))
-        if materialise:
-            cm_miss = _lane_scan(jnp.where(match & miss, pos, -1),
-                                 jnp.maximum, _INT_MIN)
+        if track_miss:
             last_miss = jnp.maximum(last_miss, jnp.max(
                 jnp.where(last_lane, cm_miss, -1), axis=1, keepdims=True))
         run_cycles = end_cum - q_cycles + jnp.where(do_switch, handler, 0)
@@ -243,7 +264,7 @@ def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
         add(_CYCLES, run_cycles)
         add(_INSTRS, n)
         add(_MISSES, jnp.sum((miss & in_run).astype(jnp.int32)))
-        add(_BS_MISSES, jnp.sum((cold & in_run).astype(jnp.int32)))
+        add(_BS_MISSES, jnp.sum((bs_miss & in_run).astype(jnp.int32)))
         return (jnp.maximum(last_pos, committed), last_miss,
                 jnp.where(do_switch, (sched_idx + 1) % sched_len,
                           sched_idx),
@@ -279,13 +300,15 @@ def _kernel(counts_ref, lats_ref, quanta_ref, sched_ref, misc_ref, seed_ref,
 def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
          handler, bs_miss_extra, seed=None, *, num_tags: int,
          total_steps: int, window: int, pos_base: int, materialise: bool,
-         interpret, name: str):
+         interpret, name: str, bs_entries: int | None = None):
     """The shared pallas_call: (B, P, N) streams over the (Q, B, K, L)
     cell grid, every cell starting from the same `seed` — None (cold) or
     (flat SMEM seed, (num_tags,) last_pos).  Returns the (Q, B, K, L, 8,
     128) counter tiles and the (Q, B, K, L, t_pad, 128) last_pos (lane 0)
     / last_miss (lane 1) tiles.  `name` is the kernel's HLO instruction
-    name, which device traces show whatever jit encloses the call."""
+    name, which device traces show whatever jit encloses the call.
+    `bs_entries` is the capacity of a bitstream cache smaller than the
+    tag set (None: warm), compiled into the kernel."""
     ptags = jnp.asarray(ptags, jnp.int32)
     num_fleets, num_progs, trace_len = ptags.shape
     if num_progs > _LANES:
@@ -314,7 +337,7 @@ def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
     kernel = functools.partial(
         _kernel, num_progs=num_progs, trace_len=trace_len,
         total_steps=int(total_steps), window=int(window), w_pad=w_pad,
-        pos_base=pos_base, materialise=materialise)
+        pos_base=pos_base, materialise=materialise, bs_entries=bs_entries)
     ncells = nq * num_fleets * nk * nl
 
     def cell(q, b, k, l):
@@ -349,20 +372,23 @@ def _run(ptags, pcosts, slot_counts, miss_latencies, quanta, schedule,
 
 
 @functools.partial(jax.jit, static_argnames=("num_tags", "total_steps",
-                                             "window", "interpret"))
+                                             "window", "interpret",
+                                             "bs_entries"))
 def window_grid(ptags, pcosts, slot_counts, miss_latencies, quanta,
                 schedule, handler, bs_miss_extra, *, num_tags: int,
-                total_steps: int, window: int, interpret=None):
+                total_steps: int, window: int, interpret=None,
+                bs_entries: int | None = None):
     """One-shot counter sweep: (B, P, N) pre-gathered tag/cost streams ->
     the 5 `InterleavedGrid` arrays, one fused-kernel cell per point of
-    the (Q, B, K, L) Pallas grid.  Bit-for-bit equal to
+    the (Q, B, K, L) Pallas grid.  `bs_entries` below `num_tags` runs
+    the bitstream level; None is a warm cache.  Bit-for-bit equal to
     `stackdist_interleaved.sweep_preempted`'s jnp path."""
     num_progs = ptags.shape[1]
     tiles, _ = _run(ptags, pcosts, slot_counts, miss_latencies, quanta,
                     schedule, handler, bs_miss_extra, num_tags=num_tags,
                     total_steps=total_steps, window=window, pos_base=0,
                     materialise=False, interpret=interpret,
-                    name="window_grid")
+                    name="window_grid", bs_entries=bs_entries)
     return (tiles[..., _CYCLES, :num_progs], tiles[..., _INSTRS, :num_progs],
             tiles[..., _MISSES, :num_progs],
             tiles[..., _BS_MISSES, :num_progs], tiles[..., 5, 3])

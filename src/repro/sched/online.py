@@ -88,6 +88,7 @@ bit-for-bit (`run(checkpoint_every=..., save_fn=...)`).
 from __future__ import annotations
 
 import copy
+import operator
 import time
 from dataclasses import dataclass, field
 
@@ -142,8 +143,9 @@ class OnlineConfig:
     `epoch_steps` is the scan budget every non-empty core advances per
     epoch (its round-robin shares it between residents); `probe_steps` the
     resume window of the migration-penalty measurement.  `placement`
-    carries the simulator geometry (slots, miss latency, quantum) shared
-    by the epoch scans, the contention model, and the probes.
+    carries the simulator geometry (slots, miss latency, quantum, the
+    bitstream cache and its miss penalty) shared by the epoch scans, the
+    contention model, and the probes.
 
     `topology` (a `repro.sched.topology.Topology`) places the cores
     within sockets within hosts; when given, it *defines* `num_cores`.
@@ -157,8 +159,6 @@ class OnlineConfig:
     # soft per-epoch migration bound: no new exchange unit starts once this
     # many tenants moved (an atomic cycle may overshoot by its length - 1)
     max_moves_per_epoch: int = 4
-    bs_cache_entries: int = 64
-    bs_miss_extra: int = 100
     placement: PlacementConfig = field(default_factory=PlacementConfig)
     topology: Topology | None = None
 
@@ -181,8 +181,8 @@ class OnlineConfig:
         return simulator.ReconfigConfig(
             num_slots=self.placement.num_slots,
             miss_latency=self.placement.miss_latency,
-            bs_cache_entries=self.bs_cache_entries,
-            bs_miss_extra=self.bs_miss_extra)
+            bs_cache_entries=self.placement.bs_cache_entries,
+            bs_miss_extra=self.placement.bs_miss_extra)
 
 
 class _TenantRun:
@@ -212,7 +212,7 @@ class _Core:
 
     def __init__(self, cfg: OnlineConfig):
         self.slot_st = slots.init(cfg.placement.num_slots)
-        self.bs_st = slots.init(cfg.bs_cache_entries)
+        self.bs_st = slots.init(cfg.placement.bs_cache_entries)
         self.up = True
         self.active_slots = cfg.placement.num_slots
         self.repair_at: int | None = None    # epoch a transient loss heals
@@ -291,12 +291,14 @@ class OnlineReplacer:
             raise ValueError(f"backoff_cap must be >= 1, got {backoff_cap}")
         self.cfg = cfg or OnlineConfig()
         self.model = model or ContentionModel(self.cfg.placement)
-        if self.model.cfg.num_slots != self.cfg.placement.num_slots:
+        core_of = operator.attrgetter("num_slots", "bs_cache_entries",
+                                      "bs_miss_extra")
+        if core_of(self.model.cfg) != core_of(self.cfg.placement):
             raise ValueError(
-                f"contention model simulates {self.model.cfg.num_slots} "
-                f"slots but the online config serves "
-                f"{self.cfg.placement.num_slots} — predictions would price "
-                f"a different machine")
+                f"contention model simulates (slots, bitstream entries, "
+                f"bitstream miss extra) {core_of(self.model.cfg)} but the "
+                f"online config serves {core_of(self.cfg.placement)} — "
+                f"predictions would price a different machine")
         self.policy = policy
         self.faults = faults
         self.recovery = recovery
@@ -426,7 +428,7 @@ class OnlineReplacer:
                 1, self.cfg.placement.num_slots - core.repair_degraded)
             core.repair_degraded = 0
             core.slot_st = slots.init(self.cfg.placement.num_slots)
-            core.bs_st = slots.init(self.cfg.bs_cache_entries)
+            core.bs_st = slots.init(self.cfg.placement.bs_cache_entries)
             self._mark_dirty(ci)           # up-set changed: host re-solves
             self.fault_log.append({"epoch": epoch, "kind": "repair",
                                    "core": ci,
@@ -461,7 +463,7 @@ class OnlineReplacer:
                     core.slot_st = simulator.canonical_slot_state(
                         slots.invalidate(core.slot_st, hit))
             elif ev.kind == "bitstream_flush":
-                core.bs_st = slots.init(self.cfg.bs_cache_entries)
+                core.bs_st = slots.init(self.cfg.placement.bs_cache_entries)
             else:                                   # reconfig_stall
                 core.stall_until = max(core.stall_until,
                                        epoch + ev.stall_epochs)
@@ -503,7 +505,7 @@ class OnlineReplacer:
         pcfg = self.cfg.placement
         core = self.cores[dst]
         st = simulator.init_fleet_state(
-            1, pcfg.num_slots, self.cfg.bs_cache_entries)._replace(
+            1, pcfg.num_slots, pcfg.bs_cache_entries)._replace(
                 slot_st=core.slot_st, bs_st=core.bs_st,
                 cursors=jnp.asarray([t.cursor], jnp.int32))
         na = (core.active_slots
@@ -590,7 +592,7 @@ class OnlineReplacer:
             tr = np.stack([np.asarray(self.model.trace(t.bench))
                            for t in members])
             st = simulator.init_fleet_state(
-                len(members), pcfg.num_slots, self.cfg.bs_cache_entries)
+                len(members), pcfg.num_slots, pcfg.bs_cache_entries)
             # resume: the core's caches are warm from every prior epoch
             # (and from prior residents); cursors continue each tenant's
             # own stream; counters start at zero -> per-epoch deltas
@@ -641,7 +643,7 @@ class OnlineReplacer:
         res = slots.resident_many(self.cores[t.core].bs_st,
                                   jnp.asarray(tags, jnp.int32))
         resident = int(np.sum(np.asarray(res)))
-        return float(resident * self.cfg.bs_miss_extra * mult)
+        return float(resident * self.cfg.placement.bs_miss_extra * mult)
 
     def migration_penalty(self, name: str, dst: int | None = None) -> float:
         """Cost (cycles) of restarting `name` on another core.
@@ -666,7 +668,7 @@ class OnlineReplacer:
         scen = self.model.scenario_of(t.bench)
         tr = np.asarray(self.model.trace(t.bench))[None, :]
         cold = simulator.init_fleet_state(
-            1, pcfg.num_slots, self.cfg.bs_cache_entries)._replace(
+            1, pcfg.num_slots, pcfg.bs_cache_entries)._replace(
                 cursors=jnp.asarray([t.cursor], jnp.int32))
         core = self.cores[t.core]
         warm = cold._replace(slot_st=core.slot_st, bs_st=core.bs_st)
@@ -940,7 +942,8 @@ class OnlineReplacer:
                     if core.up:
                         core.slot_st = slots.init(
                             self.cfg.placement.num_slots)
-                        core.bs_st = slots.init(self.cfg.bs_cache_entries)
+                        core.bs_st = slots.init(
+                            self.cfg.placement.bs_cache_entries)
                 self.fault_log.append({"epoch": epoch,
                                        "kind": "cold_restart"})
             todays = by_epoch.get(epoch, [])
@@ -1016,7 +1019,7 @@ class OnlineReplacer:
             "topology": self.cfg.topology.geometry(),
             "num_cores": self.cfg.num_cores,
             "num_slots": self.cfg.placement.num_slots,
-            "bs_entries": self.cfg.bs_cache_entries,
+            "bs_entries": self.cfg.placement.bs_cache_entries,
             "migrations": self.migrations,
             "evacuations": self.evacuations,
             "tenants": [_tenant(self.tenants[n])
@@ -1051,7 +1054,7 @@ class OnlineReplacer:
                           ("recovery", self.recovery),
                           ("num_cores", self.cfg.num_cores),
                           ("num_slots", self.cfg.placement.num_slots),
-                          ("bs_entries", self.cfg.bs_cache_entries)):
+                          ("bs_entries", self.cfg.placement.bs_cache_entries)):
             if snap[key] != mine:
                 raise ValueError(
                     f"snapshot {key}={snap[key]!r} does not match this "

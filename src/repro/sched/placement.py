@@ -60,6 +60,12 @@ class PlacementConfig:
     # sizes (priority-aware admission is a ROADMAP direction).
     quantum_cycles: int = 2_000
     handler_cycles: int = 150
+    # the core's bitstream cache and its miss penalty; fewer entries than
+    # the tenants' merged tag set price the evictions too (the interleaved
+    # engine's bitstream level, the stacked cold pass for the solo
+    # references)
+    bs_cache_entries: int = 64
+    bs_miss_extra: int = 100
     trace_len: int = 12_000
     steps_per_program: int = 12_000   # total_steps = P * steps_per_program
 
@@ -158,6 +164,8 @@ class ContentionModel:
                 simulator.SchedulerConfig.no_preempt(
                     self.cfg.handler_cycles),
                 slot_counts=[self.cfg.num_slots],
+                bs_cache_entries=self.cfg.bs_cache_entries,
+                bs_miss_extra=self.cfg.bs_miss_extra,
                 total_steps=self.cfg.steps_per_program, path=self.path)
             self.sim_calls += 1
             cpi = np.asarray(res.cpi)[:, 0, 0, 0]
@@ -241,6 +249,8 @@ class ContentionModel:
                 [self.scenario_of(b) for b in ks[0]],
                 self.cfg.scheduler(),
                 slot_counts=[ns],
+                bs_cache_entries=self.cfg.bs_cache_entries,
+                bs_miss_extra=self.cfg.bs_miss_extra,
                 total_steps=size * self.cfg.steps_per_program,
                 path=self.path)
             self.sim_calls += 1

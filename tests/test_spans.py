@@ -3,7 +3,8 @@
 `sweep_fleet` and `sweep_bitstream` each open one entry span per call
 (`sim.sweep_fleet`, `sim.sweep_bitstream`) with their stages nested
 inside it (`sim.plan`, `sim.stage`, `sim.launch`, `sim.assemble`), and
-add the grid cells they ask for and launch to `simulator.cell_counts()`.
+add the grid cells they ask for and launch to `simulator.cell_counts()`,
+the launched cells also by the engine that ran them.
 The spans go to the JAX profiler's host plane, where the benchmark's
 per-layer readers find them; here they are read back from the profile
 the profiler writes.
@@ -21,6 +22,11 @@ ENTRY = {"sim.sweep_fleet", "sim.sweep_bitstream"}
 STAGES = ("sim.plan", "sim.stage", "sim.launch", "sim.assemble")
 SCHED = simulator.SchedulerConfig(quantum_cycles=2_000, handler_cycles=150)
 STEPS = 1_200
+
+
+def _counts(**nonzero):
+    """A `cell_counts()` advance: the given counters, every other 0."""
+    return {**dict.fromkeys(simulator.cell_counts(), 0), **nonzero}
 
 
 def _host_spans(log_dir):
@@ -91,8 +97,9 @@ def test_sweep_fleet_spans_nest_per_call_and_count_padding(tmp_path):
     assert set(inner[0]) == set(STAGES)
     assert inner[0] == inner[1]
     cells = len(quanta) * len(slots) * len(lats)
-    assert counted == {"cells_real": 2 * cells * 5,
-                       "cells_launched": 2 * cells * 8}
+    assert counted == _counts(cells_real=2 * cells * 5,
+                              cells_launched=2 * cells * 8,
+                              cells_interleaved=2 * cells * 8)
 
 
 def test_sweep_bitstream_spans_nest_per_call(tmp_path):
@@ -111,7 +118,9 @@ def test_sweep_bitstream_spans_nest_per_call(tmp_path):
     assert len(inner) == 2
     assert inner[0] == inner[1] == ["sim.plan", "sim.stage", "sim.launch"]
     cells = 2 * 2 * 1 * 3 * 2       # (B, K, L, E, X), per call
-    assert counted == {"cells_real": 2 * cells, "cells_launched": 2 * cells}
+    assert counted == _counts(cells_real=2 * cells,
+                              cells_launched=2 * cells,
+                              cells_stackdist_cold=2 * cells)
 
 
 @pytest.mark.parametrize("path,sched", [
@@ -121,7 +130,8 @@ def test_sweep_bitstream_spans_nest_per_call(tmp_path):
 ])
 def test_every_engine_path_counts_its_cells(path, sched):
     """The scan and the stack-distance passes launch exactly the cells
-    asked for; the quantum axis counts even where it is broadcast."""
+    asked for, and count them under their engine; the quantum axis
+    counts even where it is broadcast."""
     before = simulator.cell_counts()
     res = simulator.sweep_fleet(
         _fleets(3), [10, 50], isa.SCENARIO_2, sched, slot_counts=[2, 4],
@@ -129,5 +139,6 @@ def test_every_engine_path_counts_its_cells(path, sched):
         bs_cache_entries=4 if path == "stackdist_cold" else 64)
     jax.block_until_ready(res)
     after = simulator.cell_counts()
-    assert {k: after[k] - before[k] for k in after} == {
-        "cells_real": 3 * 2 * 2, "cells_launched": 3 * 2 * 2}
+    assert {k: after[k] - before[k] for k in after} == _counts(
+        cells_real=3 * 2 * 2, cells_launched=3 * 2 * 2,
+        **{f"cells_{path}": 3 * 2 * 2})

@@ -77,38 +77,42 @@ def test_auto_routes_preempted_warm_grid_through_interleaved(route_spy):
 
 
 def test_auto_cold_bitstream_cache_still_falls_back_to_scan(route_spy):
-    """An undersized bitstream cache is ineligible for BOTH fast paths;
-    auto must serve the historical scan numbers untouched."""
+    """An undersized bitstream cache on a preempted grid no longer falls
+    back: auto routes it through the interleaved engine, whose window
+    pass runs the bitstream level, and serves the scan's numbers
+    bit-for-bit."""
     fl = _preempted_fleet()
     sched = simulator.SchedulerConfig(quantum_cycles=2_000)
     kw = dict(slot_counts=[4], bs_cache_entries=4, total_steps=6_000)
     auto = simulator.sweep_fleet(fl, [50], isa.SCENARIO_2, sched, **kw)
+    assert len(route_spy) == 1
     scan = simulator.sweep_fleet(fl, [50], isa.SCENARIO_2, sched,
                                  path="scan", **kw)
-    assert not route_spy
+    assert len(route_spy) == 1
     _assert_fleet_equal(auto, scan)
 
 
 def test_warmth_is_judged_on_the_fleets_merged_tag_set(route_spy):
     """Program 1 slots more opcodes than program 0: a bitstream cache warm
-    for program 0 alone can be cold for the merged stream — eligibility
-    must use the union of the per-program tag tables."""
+    for program 0 alone can be cold for the merged stream — warmth must
+    be judged on the union of the per-program tag tables.  Both caches
+    are eligible now; the cold one compiles the bitstream level in and
+    still equals the scan."""
+    from repro.core import stackdist_interleaved as sdi
     table = simulator.fleet_tag_table([isa.SCENARIO_3, isa.SCENARIO_1], 2)
     union_tags = int(np.max(table)) + 1
     p0_tags = int(np.max(table[0])) + 1
     assert p0_tags < union_tags
-    kw = dict(miss_latencies=[50], bs_miss_extra=100, handler_cycles=150,
-              total_steps=4_000)
-    assert simulator.interleaved_eligible(table, bs_entries=union_tags,
-                                          **kw)
-    assert not simulator.interleaved_eligible(table, bs_entries=p0_tags,
-                                              **kw)
+    assert sdi.bs_level(union_tags, union_tags) is None
+    assert sdi.bs_level(p0_tags, union_tags) == p0_tags
     fl = _preempted_fleet()
     sched = simulator.SchedulerConfig(quantum_cycles=1_000)
+    before = simulator.cell_counts()["cells_bs_level"]
     auto = simulator.sweep_fleet(
         fl, [50], [isa.SCENARIO_3, isa.SCENARIO_1], sched, slot_counts=[4],
         bs_cache_entries=p0_tags, total_steps=4_000)
-    assert not route_spy                # cold for the union -> scan
+    assert len(route_spy) == 1          # cold for the union -> the level
+    assert simulator.cell_counts()["cells_bs_level"] > before
     scan = simulator.sweep_fleet(
         fl, [50], [isa.SCENARIO_3, isa.SCENARIO_1], sched, slot_counts=[4],
         bs_cache_entries=p0_tags, total_steps=4_000, path="scan")
@@ -116,30 +120,33 @@ def test_warmth_is_judged_on_the_fleets_merged_tag_set(route_spy):
 
 
 def test_interleaved_eligibility_rules():
-    table = simulator.fleet_tag_table(isa.SCENARIO_2, 2)
-    ok = dict(bs_entries=64, miss_latencies=[10, 250], bs_miss_extra=100,
+    ok = dict(miss_latencies=[10, 250], bs_miss_extra=100,
               handler_cycles=150, total_steps=40_000)
-    assert simulator.interleaved_eligible(table, **ok)
-    # cold bitstream cache (scenario 2 has 10 distinct tags)
-    assert not simulator.interleaved_eligible(table,
-                                              **{**ok, "bs_entries": 4})
+    assert simulator.interleaved_eligible(**ok)
+    # a cold bitstream cache (scenario 2 has 10 distinct tags) is
+    # eligible: the window pass runs the bitstream level
+    fl = _preempted_fleet(n=400)
+    res = simulator.sweep_fleet(fl, [50], isa.SCENARIO_2,
+                                simulator.SchedulerConfig(quantum_cycles=300),
+                                slot_counts=[4], bs_cache_entries=4,
+                                total_steps=400, path="interleaved")
+    assert int(np.sum(res.bs_misses)) > 0
     # negative costs break monotone in-window accumulation
     assert not simulator.interleaved_eligible(
-        table, **{**ok, "miss_latencies": [-1, 50]})
-    assert not simulator.interleaved_eligible(
-        table, **{**ok, "bs_miss_extra": -5})
+        **{**ok, "miss_latencies": [-1, 50]})
+    assert not simulator.interleaved_eligible(**{**ok, "bs_miss_extra": -5})
     # overflow guard
     assert not simulator.interleaved_eligible(
-        table, **{**ok, "miss_latencies": [1 << 29]})
+        **{**ok, "miss_latencies": [1 << 29]})
 
 
 def test_forcing_interleaved_on_ineligible_grid_raises():
     fl = _preempted_fleet(n=1_000)
     sched = simulator.SchedulerConfig(quantum_cycles=500)
     with pytest.raises(ValueError, match="interleaved path"):
-        simulator.sweep_fleet(fl, [50], isa.SCENARIO_2, sched,
-                              slot_counts=[4], bs_cache_entries=4,
-                              total_steps=1_000, path="interleaved")
+        simulator.sweep_fleet(fl, [-1], isa.SCENARIO_2, sched,
+                              slot_counts=[4], total_steps=1_000,
+                              path="interleaved")
     # forcing the unpreempted engine on a preempted grid still raises
     with pytest.raises(ValueError, match="stack-distance"):
         simulator.sweep_fleet(fl, [50], isa.SCENARIO_2, sched,

@@ -192,7 +192,7 @@ def test_cross_socket_and_cross_host_moves_pay_the_reload_tiers(model):
     assert cross_host == pytest.approx(
         cross_socket * topo.cross_host_reload / topo.cross_socket_reload)
     # the surcharge is resident_bitstreams x bs_miss_extra x multiplier
-    assert cross_socket % (rep.cfg.bs_miss_extra
+    assert cross_socket % (rep.cfg.placement.bs_miss_extra
                            * topo.cross_socket_reload) == 0.0
     assert rep.reload_cycles("a", 0) == 0.0            # intra_core
     assert rep.migration_penalty("a", 1) == bare + cross_socket

@@ -6,12 +6,15 @@ block shapes, dynamic indexing, scalar placement, VMEM.  These tests
 compile, without a chip, at the shapes the benchmarks and
 `chip_smoke.py` run:
 
-* `window_grid` at the Fig. 7 grid and the P=4 fleet sweep;
+* `window_grid` at the Fig. 7 grid and the P=4 fleet sweep, and at the
+  Fig. 7 grid with the bitstream level of a 4-entry bitstream cache,
+  which a warm cache's kernel leaves out;
 * `window_cell` at the chaos-serve resume shape (N=4 000) and the
   fleet-scale shape (N=768, ~8 tenants per core);
 * the jnp `_sweep_impl` at the Fig. 7 grid, which must fit one chip;
 * the fleet-axis mesh sweep (`simulator._mesh_sweep_preempted`) over
-  four chips, running the kernel on each;
+  four chips, running the kernel on each, at the Fig. 7 grid and at the
+  P=4 fleet sweep's 88 padded fleets;
 * the Fig. 7 kernel sweep and the stacked cold pass at the bitstream
   grid, which must hold no element gather or scatter over a stream:
   their lookups go through `repro.core.lookup`.
@@ -110,6 +113,68 @@ def test_window_grid_compiles(one_chip, shape):
     _fits_one_chip(compiled)
 
 
+def test_window_grid_with_bitstream_level_compiles(one_chip):
+    """The Fig. 7 grid on a core with a 4-entry bitstream cache: the
+    kernel with its bitstream level lowers under its VMEM limit, keeps
+    its pinned name, and fits one chip."""
+    compiled = jax.jit(
+        lambda *a: wd.window_grid(*a, num_tags=NUM_TAGS,
+                                  total_steps=FIG7[3], window=WINDOW,
+                                  interpret=False, bs_entries=4)
+    ).lower(*_grid_args(FIG7, one_chip)).compile()
+    names = _kernel_names(compiled)
+    assert names and all(n.startswith("%window_grid") for n in names), names
+    _fits_one_chip(compiled)
+
+
+def _eqns(jaxpr) -> int:
+    """Equations of a jaxpr, those of its nested jaxprs included."""
+    n = 0
+    for e in jaxpr.eqns:
+        n += 1
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _eqns(inner)
+    return n
+
+
+def _kernel_body_ops(bs_entries) -> int:
+    """The operations of the Fig. 7 window kernel's body, as traced."""
+    b, p, n, steps, q, k, l = FIG7
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    closed = jax.make_jaxpr(
+        lambda *a: wd.window_grid(*a, num_tags=NUM_TAGS, total_steps=steps,
+                                  window=WINDOW, interpret=False,
+                                  bs_entries=bs_entries)
+    )(s(b, p, n), s(b, p, n), s(k), s(l), s(q, p), s(p), s(), s())
+
+    def find(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                return e.params["jaxpr"]
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None and hasattr(inner, "eqns"):
+                    found = find(inner)
+                    if found is not None:
+                        return found
+        return None
+
+    return _eqns(find(closed.jaxpr))
+
+
+def test_warm_kernel_leaves_the_bitstream_level_out():
+    """A 64-entry bitstream cache holds Fig. 7's ten tags: the engine
+    passes the kernel no bitstream level, and the kernel it then runs
+    has fewer operations than the 4-entry core's, whose level it
+    leaves out."""
+    warm = sdi.bs_level(64, NUM_TAGS)
+    assert warm is None
+    assert _kernel_body_ops(warm) < _kernel_body_ops(4)
+
+
 # (programs, trace length, steps): a chaos-serve epoch advance and probe
 # (PlacementConfig trace_len 4 000, epoch 8 000 / probe 2 000 steps), and
 # a fleet-scale core of 8 tenants (trace_len 768, epoch 1 024 steps)
@@ -167,6 +232,30 @@ def test_fleet_mesh_sweep_compiles_for_four_chips(topo, monkeypatch):
     ).lower(part).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _fits_one_chip(compiled) > 0
+
+
+def test_p4_mesh_sweep_compiles_for_four_chips(topo, monkeypatch):
+    """The P=4 fleet sweep's 85 fleets, padded to 88, over a 2x2 mesh:
+    each chip runs the kernel, under its pinned name, on 22 fleets x 3
+    latencies."""
+    monkeypatch.setattr(sdi.window_distance, "resolve",
+                        lambda use_kernel=None: (True, False))
+    mesh = Mesh(np.array(topo.devices), ("fleet",))
+    _, p, n, steps, _, _, _ = P4_FLEETS
+    table = simulator.fleet_tag_table(isa.SCENARIO_2, p)
+    part = jax.ShapeDtypeStruct((88, p, n), jnp.int32,
+                                sharding=NamedSharding(
+                                    mesh, PartitionSpec("fleet")))
+    compiled = jax.jit(
+        lambda pt: simulator._mesh_sweep_preempted(
+            mesh, pt, table, jnp.asarray([4], jnp.int32),
+            jnp.asarray([10, 50, 250], jnp.int32),
+            np.asarray([[20_000] * p]), np.arange(p), 150, 100, NUM_TAGS,
+            steps, WINDOW, None, 64)
+    ).lower(part).compile()
+    names = _kernel_names(compiled)
+    assert names and all(n.startswith("%window_grid") for n in names), names
+    _fits_one_chip(compiled)
 
 
 _DEF = re.compile(r"^\s*(?:ROOT\s+)?(%[\w.\-]+) = \w+\[([\d,]*)\]")
